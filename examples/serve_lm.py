@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs import ARCHS, get_config
 from repro.configs.base import InputShape
 from repro.data.tokens import lm_batch
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import transformer as tr
 
@@ -33,7 +34,7 @@ def main():
 
     cfg = get_config(args.arch, reduced_variant=True)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_mesh((1, n_dev), ("data", "model"))
     capacity = args.prompt_len + args.tokens + (cfg.n_patches or 0)
     params = tr.init_lm(jax.random.PRNGKey(0), cfg)
     prompts, _ = lm_batch(0, args.batch, args.prompt_len, cfg.vocab)

@@ -24,6 +24,7 @@ from repro import checkpoint
 from repro.configs import ARCHS, get_config
 from repro.configs.base import InputShape
 from repro.data.tokens import lm_batch
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import (OacServerConfig, init_server_state,
                                 make_train_step)
 from repro.models import transformer as tr
@@ -60,7 +61,7 @@ def main():
 
     cfg = sized_config(args.arch, args.size)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_mesh((1, n_dev), ("data", "model"))
     shape = InputShape("custom", args.seq, args.batch, "train")
     oac = (OacServerConfig(rho=args.rho, noise_std=args.noise)
            if args.oac else None)

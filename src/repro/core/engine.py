@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import packing, selection
 
 Array = jax.Array
@@ -1110,13 +1109,14 @@ class SelectionEngine:
                 (), jnp.float32)
             return g_t, age_next, res_next, n_sel, part, sel_out
 
-        fn = compat.shard_map(
-            shard_phase, mesh,
+        fn = jax.shard_map(
+            shard_phase, mesh=mesh,
             in_specs=(vec, vec, vec, vec if has_res else P(), P(), P(),
                       P(), P()),
             out_specs=(vec, vec, vec if has_res else P(), P(),
                        (P(), P(), P()),
-                       vec if age_lag is not None else P()))
+                       vec if age_lag is not None else P()),
+            check_vma=False)
         if key is None:
             key = jax.random.PRNGKey(0)
         res_in = residual if has_res else jnp.zeros((), jnp.float32)

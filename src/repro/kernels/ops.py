@@ -1,7 +1,7 @@
 """Dispatching wrappers for the Pallas kernels.
 
-On TPU the real ``pl.pallas_call`` kernels run; elsewhere (this CPU
-container) the kernels execute in ``interpret=True`` mode when explicitly
+On TPU the real ``pl.pallas_call`` kernels run; elsewhere (the CPU)
+the kernels execute in ``interpret=True`` mode when explicitly
 requested (tests) or fall through to the pure-jnp oracles in ``ref.py``
 (fast XLA path, used by benchmarks and the dry-run)."""
 
@@ -18,8 +18,9 @@ from repro.core.packing import PAD_AGE
 from repro.kernels import ref
 from repro.kernels.aou_merge import aou_merge_pallas
 from repro.kernels.block_topk import block_topk_pallas
-from repro.kernels.fairk_update import (STATS_AGE_OFF, STATS_MAG_OFF,
-                                        STATS_N_SEL, STATS_N_SEL_M,
+from repro.kernels.fairk_update import (BLOCK_QUANTUM, LANES,
+                                        STATS_AGE_ROW, STATS_COUNT_ROW,
+                                        STATS_MAG_ROW,
                                         fairk_ef_update_pallas,
                                         fairk_stats_update_pallas)
 from repro.kernels.sign_mv import sign_from_energy_pallas, sign_mv_pallas
@@ -159,10 +160,10 @@ def fairk_ef_update(g: Array, g_prev: Array, age: Array, theta_m, theta_a,
     ``fresh``: merged fresh values when they differ from the score source
     (the one-bit FSK-MV sign vector from ``sign_mv``).
 
-    Accepts any length: non-block-aligned inputs (e.g. arbitrary parameter
-    leaves routed through the SelectionEngine) are padded to the block grid
-    (age pad = PAD_AGE sentinel, so padding can never select) and sliced
-    back.  Interior pads of packed buffers (core.packing) use the same
+    Accepts any length: inputs that are not a multiple of 128 (e.g.
+    arbitrary parameter leaves routed through the SelectionEngine) are
+    padded to the next one (age pad = PAD_AGE sentinel, so padding can
+    never select) and sliced back.  Interior pads of packed buffers (core.packing) use the same
     sentinel and pass through untouched (incl. their residual)."""
     global FAIRK_UPDATE_CALLS
     FAIRK_UPDATE_CALLS += 1
@@ -187,16 +188,15 @@ def fairk_ef_update(g: Array, g_prev: Array, age: Array, theta_m, theta_a,
 
 
 def _block_pad(g, g_prev, age, residual, fresh, block_size):
-    """Lane-align the block (multiple of 256) so small/odd leaves don't
-    hand Mosaic an unaligned 1-D tile; size it from the trip count so
-    padding stays < 256 * nb instead of block-1 (d = block_size + 1 must
-    not double the HBM traffic of this bandwidth-bound pass).  Pads carry
-    the PAD_AGE sentinel, so they can neither select nor count."""
+    """Lane-align the streams for the kernel's (d/128, 128) view and pick
+    its block: ``block_size`` rounded up to the (8, 128) tile quantum, or
+    the whole buffer when that is smaller.  The packed server buffers are
+    already lane-aligned, so on the launch path nothing is padded (a pad
+    is a full copy of every stream); odd-length leaves get < 128 pads,
+    carrying the PAD_AGE sentinel so they can neither select nor count.
+    A partial last block is masked inside the kernel."""
     d = g.shape[0]
-    nb = -(-d // block_size)              # trip count at the requested block
-    per_block = -(-d // nb)
-    block = -(-per_block // 256) * 256    # lane-aligned actual block
-    pad = nb * block - d
+    pad = -d % LANES
     if pad:
         g, g_prev = (jnp.pad(x, (0, pad)) for x in (g, g_prev))
         age = jnp.pad(age, (0, pad), constant_values=PAD_AGE)
@@ -204,6 +204,7 @@ def _block_pad(g, g_prev, age, residual, fresh, block_size):
             residual = jnp.pad(residual, (0, pad))
         if fresh is not None:
             fresh = jnp.pad(fresh, (0, pad))
+    block = min(-(-block_size // BLOCK_QUANTUM) * BLOCK_QUANTUM, d + pad)
     return g, g_prev, age, residual, fresh, block, d
 
 
@@ -240,16 +241,14 @@ def fairk_stats_update(g: Array, g_prev: Array, age: Array, theta_m,
                                           sanitize=sanitize)
     g, g_prev, age, residual, fresh, block, d = _block_pad(
         g, g_prev, age, residual, fresh, block_size)
-    g_t, age_out, res_out, rows = fairk_stats_update_pallas(
+    g_t, age_out, res_out, tiles = fairk_stats_update_pallas(
         g, g_prev, age, tm, ta, residual=residual, fresh=fresh,
         block_size=block, interpret=(mode == "interpret"),
         stats_stride=stride, sanitize=sanitize)
-    vec = rows.sum(axis=0)                 # one tiny (nb, 384) reduction
-    stats = {"n_sel": vec[STATS_N_SEL], "n_sel_m": vec[STATS_N_SEL_M],
-             "mag_hist": vec[STATS_MAG_OFF:STATS_MAG_OFF
-                             + packing.STATS_MAG_BINS],
-             "age_hist": vec[STATS_AGE_OFF:STATS_AGE_OFF
-                             + packing.STATS_AGE_BINS]}
+    tile = tiles.sum(axis=0)               # one tiny (nb, 8, 128) reduction
+    stats = {"n_sel": tile[STATS_COUNT_ROW, 0],
+             "n_sel_m": tile[STATS_COUNT_ROW, 1],
+             "mag_hist": tile[STATS_MAG_ROW], "age_hist": tile[STATS_AGE_ROW]}
     if g.shape[0] != d:
         return (g_t[:d], age_out[:d],
                 res_out[:d] if res_out is not None else None, stats)

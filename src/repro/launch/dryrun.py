@@ -1,13 +1,18 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, prove memory/sharding coherence, and dump the roofline
 artifacts (memory_analysis, cost_analysis, loop-aware parsed HLO metrics).
 
-The XLA_FLAGS line above MUST precede every other import (jax locks the
-device count at first init) — that is why it sits before the docstring's
-siblings here and why nothing else in the repo sets it globally.
+A CPU-placeholder tool: the 512 "devices" are host CPU devices, so what it
+reports is sharding coherence and compiled-program structure, never a chip
+measurement.  The two environment lines above MUST precede every other
+import (jax locks the platform and device count at first init) — that is
+why they sit before the docstring's siblings here and why nothing else in
+the repo sets them globally.  ``JAX_PLATFORMS=cpu`` keeps it off an
+attached accelerator, which one process at a time may hold.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-32b --shape train_4k
@@ -26,8 +31,7 @@ from repro.configs import ARCHS, SHAPES, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (make_fl_oac_step, make_prefill_step,
                                 make_serve_step, make_train_step)
-from repro.roofline import (analyze_hlo, build_report, suggestion,
-                            xla_cost_analysis)
+from repro.roofline import analyze_hlo, build_report, suggestion
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "benchmarks", "artifacts", "dryrun")
@@ -85,7 +89,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
     print(mem)                               # proves it fits
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     print({k: v for k, v in cost.items()
            if k in ("flops", "bytes accessed", "transcendentals")})
     parsed = analyze_hlo(compiled.as_text())

@@ -38,7 +38,6 @@ from math import prod as np_prod
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import InputShape, ModelConfig
 from repro.core import channel as chan
 from repro.core import controller as budget
@@ -787,10 +786,10 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
                                       params, updates)
             return new_params, new_opt, new_server
 
-        update_sharded = compat.shard_map(
-            update_phase, mesh,
+        update_sharded = jax.shard_map(
+            update_phase, mesh=mesh,
             in_specs=(p_specs, o_specs, srv_specs, p_specs, P()),
-            out_specs=(p_specs, o_specs, srv_specs))
+            out_specs=(p_specs, o_specs, srv_specs), check_vma=False)
     else:
         def update_sharded(params, opt_state, server, grads, seed):
             updates, new_opt = opt.update(grads, opt_state, params)
@@ -1106,9 +1105,10 @@ def make_fl_oac_step(cfg: ModelConfig, mesh, *, seq_len: int = 1024,
     }
     b_pspec = {"tokens": P(axes, None), "labels": P(axes, None)}
     ctrl_in = (P(),) if adaptive_km else ()
-    fn = compat.shard_map(fl_oac_step, mesh,
-                          in_specs=(P(), P(), P(), *ctrl_in, b_pspec, P()),
-                          out_specs=(P(), P(), P(), *ctrl_in, P()))
+    fn = jax.shard_map(fl_oac_step, mesh=mesh,
+                       in_specs=(P(), P(), P(), *ctrl_in, b_pspec, P()),
+                       out_specs=(P(), P(), P(), *ctrl_in, P()),
+                       check_vma=False)
     named = lambda s: shlib.to_named(s, mesh)
     repl = NamedSharding(mesh, P())
     ctrl_sh = (repl,) if adaptive_km else ()

@@ -1,10 +1,14 @@
 """Training launcher.
 
-Runs real steps (reduced configs on this host's devices) or, with
-``--dryrun``, defers to ``repro.launch.dryrun`` for the production mesh.
+Runs real steps of a registered architecture on this host's devices: on
+the CPU for tests (reduced configs), on a TPU through the same loop
+(``chip_smoke.py`` at the repo root calls ``run`` below).
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-32b --reduced \
       --steps 20 --policy fairk
+
+``--mesh DxM`` lays the devices out as ``data`` (FL clients, FSDP) x
+``model`` (tensor parallelism); the default is one device.
 
 Checkpointing (packed server phase): ``--ckpt-every N`` saves the
 persisted flat server buffers (incl. the warm-start theta vector and the
@@ -12,6 +16,10 @@ adaptive-``k_M`` controller state) every N steps via
 ``repro.checkpoint.save_server_state``; a SIGTERM lands one final save
 before the loop exits; ``--resume`` restores the latest checkpoint from
 ``--ckpt-dir`` and continues at the following step.
+
+Compiled programs persist across processes (``enable_compile_cache``):
+where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX keeps them there;
+otherwise they go to ``.jax_cache/`` at the repository root.
 """
 
 from __future__ import annotations
@@ -21,22 +29,43 @@ import os
 import signal
 import time
 import zipfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint
-from repro.configs import ARCHS, SHAPES, get_config
+from repro.configs import ARCHS, get_config
 from repro.configs.base import InputShape
+from repro.core import packing
 from repro.data.tokens import lm_batch
 from repro.launch import sharding as shlib
+from repro.launch.mesh import make_mesh, parse_mesh
 from repro.launch.steps import (OacServerConfig, init_server_state,
                                 make_train_step, server_layout)
 from repro.models import transformer as tr
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
-def main():
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own default for the
+    cache directory, and no other is set here.  Otherwise the cache goes to
+    the fixed ``.jax_cache/`` at the repository root: the directory is part
+    of an entry's key, so it must not move between runs.  Call it from an
+    entry point, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen2.5-32b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -44,6 +73,10 @@ def main():
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--mesh", default="1x1",
+                    help="device mesh DxM: D data shards (FL clients, "
+                         "FSDP) x M model shards, over the first D*M "
+                         "devices")
     ap.add_argument("--oac", action="store_true", default=True,
                     help="enable the FAIR-k OAC server phase")
     ap.add_argument("--no-oac", dest="oac", action="store_false")
@@ -155,12 +188,11 @@ def main():
                          "step — gradient memory scales with the chunk, "
                          "not the client count (0 = one fused batch)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    cfg = get_config(args.arch, reduced_variant=args.reduced)
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
-    shape = InputShape("custom", args.seq, args.batch, "train")
+
+def build_oac(args) -> "OacServerConfig | None":
+    """The OAC server configuration the CLI flags describe."""
     population = None
     if args.population > 0:
         from repro.core.population import PopulationConfig
@@ -177,23 +209,49 @@ def main():
                                  csi_err=args.csi_err,
                                  rho_f=args.fading_corr,
                                  block=args.fade_block)
-    oac = (OacServerConfig(rho=args.rho, packed=not args.per_leaf_server,
-                           error_feedback=args.ef, one_bit=args.one_bit,
-                           fused_stats=not args.legacy_stats,
-                           adaptive_km=args.adaptive_km,
-                           async_agg=args.async_agg,
-                           straggler_frac=args.straggler_frac,
-                           sanitize=args.sanitize, fade=args.fade,
-                           fade_block=args.fade_block,
-                           population=population, wireless=wireless)
-           if args.oac else None)
-    n_micro = args.client_chunk or 1
-    if args.batch % n_micro:
+    return (OacServerConfig(rho=args.rho, packed=not args.per_leaf_server,
+                            error_feedback=args.ef, one_bit=args.one_bit,
+                            fused_stats=not args.legacy_stats,
+                            adaptive_km=args.adaptive_km,
+                            async_agg=args.async_agg,
+                            straggler_frac=args.straggler_frac,
+                            sanitize=args.sanitize, fade=args.fade,
+                            fade_block=args.fade_block,
+                            population=population, wireless=wireless)
+            if args.oac else None)
+
+
+def run(args) -> dict:
+    """The training loop behind the CLI: build the mesh, the step and the
+    state, compile once, then take ``args.steps`` steps.
+
+    Returns what a caller measuring the run needs: ``compile_s`` (lower +
+    compile of the step), per-step ``losses``, ``step_s`` (host clock
+    around each step up to ``block_until_ready``), ``sel_frac`` (the
+    share of this shard's coordinates the server phase selected, from the
+    fused kernel's counts carried in the threshold state; None without the
+    packed phase) and the ``compiled`` step."""
+    cfg = get_config(args.arch, reduced_variant=args.reduced)
+    data, model = parse_mesh(args.mesh)
+    if data * model > len(jax.devices()):
+        raise ValueError(f"--mesh {args.mesh} needs {data * model} devices, "
+                         f"{len(jax.devices())} present")
+    mesh = make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:data * model])
+    shape = InputShape("custom", args.seq, args.batch, "train")
+    oac = build_oac(args)
+    # --client-chunk C: C client microbatches, vmapped as one chunk;
+    # otherwise the step builder's default of one sample per data shard
+    # per microstep
+    if args.client_chunk and args.batch % args.client_chunk:
         raise ValueError(f"--client-chunk {args.client_chunk} must divide "
                          f"--batch {args.batch}")
-    bundle = make_train_step(cfg, shape, mesh, n_micro=n_micro,
+    bundle = make_train_step(cfg, shape, mesh,
+                             n_micro=(args.client_chunk or None),
                              client_chunk=(args.client_chunk or None),
                              oac=oac, lr=1e-3)
+    n_micro = bundle.meta["n_micro"]
+    in_sh, out_sh = bundle.in_shardings, bundle.out_shardings
 
     key = jax.random.PRNGKey(args.seed)
     params = tr.init_lm(key, cfg)
@@ -269,7 +327,7 @@ def main():
                 start = last
                 restored = True
                 print(f"[train] resumed server + params/opt state from "
-                      f"step {last} ({args.ckpt_dir})")
+                      f"step {last} ({args.ckpt_dir})", flush=True)
                 break
             if not restored:
                 raise ValueError(
@@ -279,13 +337,11 @@ def main():
                     "restart the trajectory from scratch")
 
     # a SIGTERM (preemption) finishes the in-flight step, saves once, and
-    # exits the loop cleanly
+    # exits the loop cleanly; the caller's handler is back once run() ends
     stop = {"sig": False}
 
     def _on_term(signum, frame):
         stop["sig"] = True
-
-    signal.signal(signal.SIGTERM, _on_term)
 
     def save(step):
         path = checkpoint.save_server_state(args.ckpt_dir, server,
@@ -296,44 +352,89 @@ def main():
                                         "opt": opt_state}, step=step)
         print(f"  [ckpt] saved {path} (+ step_{step:08d}.npz)", flush=True)
 
+    def make_batch(t):
+        toks, labels = lm_batch(args.seed * 1000 + t, args.batch, args.seq,
+                                cfg.vocab)
+        mb = args.batch // n_micro
+        batch = {"tokens": jnp.asarray(toks).reshape((n_micro, mb, args.seq)),
+                 "labels": jnp.asarray(labels).reshape(
+                     (n_micro, mb, args.seq))}
+        if cfg.family == "vlm":
+            batch["embeds"] = jnp.zeros(
+                (n_micro, mb, cfg.n_patches, cfg.d_model),
+                jnp.dtype(cfg.compute_dtype))
+        if cfg.family == "audio":
+            batch["frames"] = jnp.zeros(
+                (n_micro, mb, cfg.encoder_seq, cfg.d_model),
+                jnp.dtype(cfg.compute_dtype))
+        return batch
+
     # donate (params, opt_state, server): the persisted packed server
     # buffers (flat g_prev bf16 / age int8 / EF residual f32 / controller
     # vec) are consumed and rebuilt every step — donation makes the
     # update fully in place
-    step_fn = jax.jit(bundle.fn, donate_argnums=(0, 1, 2))
+    params, opt_state, server = jax.device_put((params, opt_state, server),
+                                               in_sh[:3])
+    step_fn = jax.jit(bundle.fn, in_shardings=in_sh, out_shardings=out_sh,
+                      donate_argnums=(0, 1, 2))
     print(f"[train] {cfg.name}: {cfg.param_count()/1e6:.1f}M-param family "
-          f"variant, {args.steps} steps, oac={'on' if args.oac else 'off'}")
-    with mesh:
-        for t in range(start, start + args.steps):
-            toks, labels = lm_batch(args.seed * 1000 + t, args.batch,
-                                    args.seq, cfg.vocab)
-            mb = args.batch // n_micro
-            batch = {"tokens": jnp.asarray(toks).reshape(
-                         (n_micro, mb, args.seq)),
-                     "labels": jnp.asarray(labels).reshape(
-                         (n_micro, mb, args.seq))}
-            if cfg.family == "vlm":
-                batch["embeds"] = jnp.zeros(
-                    (n_micro, mb, cfg.n_patches, cfg.d_model),
-                    jnp.dtype(cfg.compute_dtype))
-            if cfg.family == "audio":
-                batch["frames"] = jnp.zeros(
-                    (n_micro, mb, cfg.encoder_seq, cfg.d_model),
-                    jnp.dtype(cfg.compute_dtype))
-            t0 = time.time()
-            params, opt_state, server, loss = step_fn(
-                params, opt_state, server, batch, jnp.asarray(t, jnp.int32))
-            print(f"  step {t:3d} loss {float(loss):.4f} "
-                  f"({time.time()-t0:.2f}s)", flush=True)
-            if ckpt_on and args.ckpt_every > 0 and (
-                    (t + 1 - start) % args.ckpt_every == 0):
-                save(t + 1)
-            if stop["sig"]:
-                if ckpt_on:
+          f"variant, mesh {args.mesh}, {args.steps} steps, "
+          f"oac={'on' if args.oac else 'off'}", flush=True)
+    # the share of this shard's valid coordinates the fused pass selected:
+    # the kernel's count (pmean'd over shards) rides the threshold state
+    d_valid = (server_layout(params, shlib.param_pspecs(params, cfg, mesh),
+                             mesh).d_valid
+               if oac is not None and oac.packed else None)
+    out = {"losses": [], "step_s": [], "sel_frac": []}
+    prev_term = signal.signal(signal.SIGTERM, _on_term)
+    try:
+        with mesh:
+            t0 = time.perf_counter()
+            compiled = step_fn.lower(
+                params, opt_state, server, make_batch(start),
+                jnp.asarray(start, jnp.int32)).compile()
+            out["compile_s"] = time.perf_counter() - t0
+            out["compiled"] = compiled
+            print(f"[train] compiled the step in {out['compile_s']:.1f}s",
+                  flush=True)
+            for t in range(start, start + args.steps):
+                batch = make_batch(t)
+                t0 = time.perf_counter()
+                seed = jnp.asarray(t, jnp.int32)
+                params, opt_state, server, loss = compiled(
+                    params, opt_state, server, batch, seed)
+                jax.block_until_ready((params, opt_state, server, loss))
+                dt = time.perf_counter() - t0
+                out["losses"].append(float(loss))
+                out["step_s"].append(dt)
+                sel = None
+                if d_valid:
+                    n_sel = np.asarray(server["theta"])[
+                        packing.THRESHOLD_STATE_FIELDS.index("n_sel")]
+                    sel = float(n_sel) / d_valid
+                out["sel_frac"].append(sel)
+                print(f"  step {t:3d} loss {out['losses'][-1]:.4f} ({dt:.2f}s)"
+                      + (f" selected {sel:.4f}" if sel is not None else ""),
+                      flush=True)
+                if ckpt_on and args.ckpt_every > 0 and (
+                        (t + 1 - start) % args.ckpt_every == 0):
                     save(t + 1)
-                print("[train] SIGTERM — state saved, exiting", flush=True)
-                break
-    print("[train] done")
+                if stop["sig"]:
+                    if ckpt_on:
+                        save(t + 1)
+                    print("[train] SIGTERM — state saved, exiting",
+                          flush=True)
+                    break
+    finally:
+        signal.signal(signal.SIGTERM, prev_term)
+    print("[train] done", flush=True)
+    return out
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

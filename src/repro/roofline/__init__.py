@@ -1,10 +1,5 @@
-"""Roofline analysis from compiled dry-run artifacts (DESIGN.md §6).
+"""Roofline analysis from compiled dry-run artifacts (DESIGN.md §6)."""
 
-``xla_cost_analysis`` is the version-portable way to read XLA's own cost
-model (``Compiled.cost_analysis()`` returns a list on JAX <= 0.4.x and a
-dict on newer releases)."""
-
-from repro.compat import cost_analysis_dict as xla_cost_analysis
 from repro.roofline import analysis, hlo
 from repro.roofline.analysis import (HBM_BW, ICI_BW, PEAK_FLOPS,
                                      RooflineReport, build_report,
@@ -13,4 +8,4 @@ from repro.roofline.hlo import analyze_hlo, parse_computations
 
 __all__ = ["analysis", "hlo", "HBM_BW", "ICI_BW", "PEAK_FLOPS",
            "RooflineReport", "build_report", "model_flops", "suggestion",
-           "analyze_hlo", "parse_computations", "xla_cost_analysis"]
+           "analyze_hlo", "parse_computations"]

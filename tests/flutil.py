@@ -6,9 +6,8 @@ discipline, same carry threading).  Used by
 
 * the golden-trajectory pins (``test_streaming.py``): every
   chaos x population x wireless x backend combination is pinned bit-exact
-  against ``tests/golden/fl_trajectories.json`` captured before the
-  streaming-aggregation refactor, so ``client_chunk=None`` can never
-  drift from the historical einsum trace, and
+  against ``tests/golden/fl_trajectories.json``, so ``client_chunk=None``
+  can never drift from the historical einsum trace, and
 * the chunk-parity matrix: chunked runs (``client_chunk`` in {1, 3, N})
   must match the single-chunk trajectory within float tolerance.
 

@@ -26,6 +26,7 @@ from repro.core import aou, markov, packing
 from repro.core.engine import (AGE_CAP, EngineConfig, SelectionEngine,
                                fair_k_masks_dynamic, make_engine, traced_km)
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 
 SDS = jax.ShapeDtypeStruct
 
@@ -349,7 +350,7 @@ def test_fl_oac_adaptive_step_runs():
     from repro.launch.steps import make_fl_oac_step
     from repro.models import transformer as tr
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = get_config("mamba2-370m", reduced_variant=True)
     b = make_fl_oac_step(cfg, mesh, seq_len=32, rho=0.05, adaptive_km=True)
     assert b.meta["adaptive_km"]

@@ -31,6 +31,7 @@ import statutil
 from repro.core import channel as chan
 from repro.core import faults, markov, packing
 from repro.core.engine import make_engine
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.channel
 
@@ -484,7 +485,7 @@ class TestLaunchWireless:
         from repro.models import transformer as tr
         from repro.optim import make_optimizer
         cfg = get_config("mamba2-370m", reduced_variant=True)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         shape = InputShape("t", 64, 2, "train")
         bundle = make_train_step(cfg, shape, mesh, oac=oac)
         params = tr.init_lm(jax.random.PRNGKey(0), cfg)
@@ -551,7 +552,7 @@ class TestLaunchWireless:
         from repro.configs.base import InputShape
         from repro.launch.steps import OacServerConfig, make_train_step
         cfg = get_config("mamba2-370m", reduced_variant=True)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         shape = InputShape("t", 64, 2, "train")
         with pytest.raises(ValueError, match="sanitize"):
             make_train_step(cfg, shape, mesh,

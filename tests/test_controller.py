@@ -29,6 +29,7 @@ import statutil
 from repro import checkpoint
 from repro.core import controller, markov, packing
 from repro.core.engine import EngineConfig, SelectionEngine
+from repro.launch.mesh import make_mesh
 
 
 def _tie_free(d, seed=0):
@@ -117,7 +118,7 @@ class TestTracedKmParity:
                       exact_theta=True, fused_stats=True)
         kw = {}
         if backend == "sharded":
-            kw["mesh"] = jax.make_mesh((1,), ("shard",))
+            kw["mesh"] = make_mesh((1,), ("shard",))
         if backend == "packed":
             kw["layout"] = packing.PackedLayout.from_tree([jnp.zeros((d,))])
         eng = SelectionEngine(EngineConfig(backend=backend, **common), d,

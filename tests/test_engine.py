@@ -11,13 +11,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import selection
 from repro.core.engine import (AGE_CAP, EngineConfig, SelectionEngine,
                                exact_thresholds, index_jitter, make_engine,
                                masked_merge, threshold_mask)
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 
 def _tie_free(d, seed=0):
@@ -48,7 +50,7 @@ class TestBackendParity:
         th = SelectionEngine(EngineConfig(backend="threshold",
                                           kernel_mode="interpret", **common),
                              d)
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         sh = SelectionEngine(EngineConfig(backend="sharded", **common), d,
                              mesh=mesh)
 
@@ -176,7 +178,7 @@ class TestEngineApi:
     def test_sharded_needs_mesh_and_divisibility(self):
         with pytest.raises(ValueError):
             make_engine("fairk", "sharded", d=128)
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         with pytest.raises(ValueError):
             SelectionEngine(EngineConfig(backend="fancy"), 128, mesh=mesh)
 

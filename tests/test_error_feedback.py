@@ -23,6 +23,7 @@ import pytest
 from repro.core import packing
 from repro.core.engine import EngineConfig, SelectionEngine
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 
 def _tie_free(d, seed=0):
@@ -64,7 +65,7 @@ class TestEngineParityEF:
         common = dict(policy="fairk", rho=0.1, k_m_frac=0.75,
                       exact_theta=True)
         ex = SelectionEngine(EngineConfig(backend="exact", **common), d)
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         sh = SelectionEngine(EngineConfig(backend="sharded", **common), d,
                              mesh=mesh)
         g1, a1, s1 = jax.jit(ex.select_and_merge)(g, gp, age, residual=res)
@@ -138,7 +139,7 @@ class TestEngineParityEF:
     def test_sharded_rejects_fresh(self):
         d = 256
         g, gp, age, _ = _tie_free(d)
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         sh = SelectionEngine(
             EngineConfig(policy="fairk", backend="sharded", rho=0.1,
                          exact_theta=True), d, mesh=mesh)

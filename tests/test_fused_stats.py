@@ -31,6 +31,7 @@ from repro import checkpoint
 from repro.core import packing
 from repro.core.engine import EngineConfig, SelectionEngine
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 
 
 def _tie_free(d, seed=0):
@@ -61,7 +62,7 @@ class TestCrossBackendStatsParity:
                       exact_theta=True, fused_stats=True)
         ex = SelectionEngine(EngineConfig(backend="exact", **common), d)
         th = SelectionEngine(EngineConfig(backend="threshold", **common), d)
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         sh = SelectionEngine(EngineConfig(backend="sharded", **common), d,
                              mesh=mesh)
         lay = packing.PackedLayout.from_tree([jnp.zeros((d,))])
@@ -179,6 +180,43 @@ class TestPadExclusion:
             np.testing.assert_array_equal(np.asarray(out_r[3][key]),
                                           np.asarray(out_k[3][key]))
 
+    @pytest.mark.parametrize("stride", [1, 32, 128, 256])
+    @pytest.mark.parametrize("sanitize", [False, True])
+    def test_kernel_sample_stride_equals_oracle(self, stride, sanitize):
+        """Every sample stride the packed buffers use (lane- and row-strided
+        samples of the (rows, 128) kernel tile), with pads, non-finite
+        scores and a partial last block: the outputs and the summed
+        per-block tiles equal the single-pass oracle."""
+        from repro.kernels.fairk_update import (STATS_AGE_ROW,
+                                                STATS_COUNT_ROW,
+                                                STATS_MAG_ROW,
+                                                fairk_stats_update_pallas)
+        rng = np.random.default_rng(stride)
+        d = 4 * 2048 + 384                     # partial last block
+        g = rng.normal(size=d).astype("f4")
+        if sanitize:                           # unsanitized NaN poisons g_t
+            g[rng.integers(0, d, 64)] = np.nan
+            g[rng.integers(0, d, 64)] = np.inf
+        age = (rng.permutation(d) % 120).astype("f4")
+        age[1000:1500] = packing.PAD_AGE
+        g, age = jnp.asarray(g), jnp.asarray(age)
+        gp = jnp.asarray(rng.normal(size=d).astype("f4"))
+        tm, ta = jnp.float32(1.1), jnp.float32(60.0)
+        g_r, age_r, _, want = ref.fairk_stats_update_ref(
+            g, gp, age, tm, ta, stats_stride=stride, sanitize=sanitize)
+        g_k, age_k, _, tiles = fairk_stats_update_pallas(
+            g, gp, age, tm, ta, block_size=2048, interpret=True,
+            stats_stride=stride, sanitize=sanitize)
+        np.testing.assert_array_equal(np.asarray(g_r), np.asarray(g_k))
+        np.testing.assert_array_equal(np.asarray(age_r), np.asarray(age_k))
+        got = np.asarray(tiles).sum(axis=0)
+        assert got[STATS_COUNT_ROW, 0] == float(want["n_sel"])
+        assert got[STATS_COUNT_ROW, 1] == float(want["n_sel_m"])
+        np.testing.assert_array_equal(got[STATS_MAG_ROW],
+                                      np.asarray(want["mag_hist"]))
+        np.testing.assert_array_equal(got[STATS_AGE_ROW],
+                                      np.asarray(want["age_hist"]))
+
 
 # ---------------------------------------------------------------------------
 # histogram-derived thresholds
@@ -276,7 +314,7 @@ class TestFusedWarmStart:
         bootstrapping per-shard thresholds every round: counts keep
         tracking the GLOBAL budget from the psum'd statistics."""
         d = 16384
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         eng = SelectionEngine(
             EngineConfig(policy="fairk", backend="sharded", rho=0.1,
                          k_m_frac=0.75, warm_start=True, fused_stats=True),
@@ -302,7 +340,7 @@ class TestFusedWarmStart:
         """No tstate -> the historical per-shard bootstrap path (with the
         stats riding along when fused_stats is on)."""
         d = 8192
-        mesh = jax.make_mesh((1,), ("shard",))
+        mesh = make_mesh((1,), ("shard",))
         g, gp, age = _tie_free(d, seed=13)
         eng = SelectionEngine(
             EngineConfig(policy="fairk", backend="sharded", rho=0.1,
@@ -424,7 +462,7 @@ class TestLaunchIntegration:
         from repro.models import transformer as tr
         from repro.optim import make_optimizer
         cfg = get_config("mamba2-370m", reduced_variant=True)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         shape = InputShape("t", 64, 2, "train")
         bundle = make_train_step(cfg, shape, mesh, oac=oac)
         params = tr.init_lm(jax.random.PRNGKey(0), cfg)
@@ -490,7 +528,7 @@ class TestLaunchIntegration:
         from repro.configs.base import InputShape
         from repro.launch.steps import OacServerConfig, make_train_step
         cfg = get_config("mamba2-370m", reduced_variant=True)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         for bad in (OacServerConfig(adaptive_km=True, packed=False),
                     OacServerConfig(adaptive_km=True, fused_stats=False)):
             with pytest.raises(ValueError):
@@ -502,7 +540,7 @@ class TestLaunchIntegration:
         from repro.configs.base import InputShape
         from repro.launch.steps import OacServerConfig, make_train_step
         cfg = get_config("mamba2-370m", reduced_variant=True)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with pytest.raises(ValueError):
             make_train_step(cfg, InputShape("t", 64, 2, "train"), mesh,
                             oac=OacServerConfig(packed=False,

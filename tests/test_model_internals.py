@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models import attention as attn
 from repro.models.mamba2 import _causal_conv, ssd_chunked, ssd_step

@@ -22,6 +22,7 @@ from repro.core.engine import (EngineConfig, SelectionEngine,
                                exact_thresholds, make_engine, masked_merge,
                                sampled_thresholds, threshold_mask)
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 
 def transformer_tree(seed=0, n_layers=3, d_model=64, vocab=500,
@@ -356,7 +357,7 @@ def test_fl_oac_age_clipped_at_cap():
     from repro.models import transformer as tr
     from jax.flatten_util import ravel_pytree
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = get_config("mamba2-370m", reduced_variant=True)
     b = make_fl_oac_step(cfg, mesh, seq_len=32, rho=0.05)
     params = tr.init_lm(jax.random.PRNGKey(0), cfg)

@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.core import packing
 from repro.launch import sharding as shlib
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import (OacServerConfig, abstract_params,
                                 abstract_server_state, init_server_state,
                                 make_train_step, server_layout)
@@ -56,7 +57,7 @@ def test_server_layout_local_shapes():
                                           (False, True), (True, True)])
 def test_init_matches_abstract_and_specs(ef, async_agg):
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     oac = OacServerConfig(error_feedback=ef, async_agg=async_agg)
     params_abs = abstract_params(cfg)
     p_specs = shlib.param_pspecs(params_abs, cfg, mesh)
@@ -94,7 +95,7 @@ def test_packed_init_requires_mesh_and_cfg():
 
 def test_per_leaf_rejects_error_feedback():
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError):
         make_train_step(cfg, InputShape("t", 64, 2, "train"), mesh,
                         oac=OacServerConfig(packed=False,
@@ -103,7 +104,7 @@ def test_per_leaf_rejects_error_feedback():
 
 def test_async_validation():
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = InputShape("t", 64, 2, "train")
     with pytest.raises(ValueError, match="packed"):
         make_train_step(cfg, shape, mesh,
@@ -239,7 +240,7 @@ def test_two_steps_execute_with_persisted_buffers(ef):
     from repro.models import transformer as tr
     from repro.optim import make_optimizer
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = InputShape("t", 64, 2, "train")
     oac = OacServerConfig(error_feedback=ef)
     bundle = make_train_step(cfg, shape, mesh, oac=oac)
@@ -279,7 +280,7 @@ def test_two_async_steps_execute_with_double_buffers():
     from repro.models import transformer as tr
     from repro.optim import make_optimizer
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = InputShape("t", 64, 2, "train")
     oac = OacServerConfig(async_agg=True, straggler_frac=0.25,
                           straggler_lag=1)

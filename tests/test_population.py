@@ -33,6 +33,7 @@ import statutil
 from repro.core import faults, markov, packing, population
 from repro.core.engine import make_engine
 from repro.core.population import PAD, PopulationConfig
+from repro.launch.mesh import make_mesh
 
 
 def _cfg(**kw):
@@ -338,7 +339,7 @@ def test_launch_population_validation():
     from repro.configs.base import InputShape
     from repro.launch.steps import OacServerConfig, make_train_step
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = InputShape("t", 64, 2, "train")
     pop = PopulationConfig(n_clients=4096, participants=16, avail=0.9)
     with pytest.raises(ValueError, match="sanitize"):

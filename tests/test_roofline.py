@@ -6,8 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import SHAPES, get_config
-from repro.roofline import (analyze_hlo, build_report, model_flops,
-                            xla_cost_analysis)
+from repro.roofline import analyze_hlo, build_report, model_flops
 from repro.roofline.hlo import _shape_bytes, parse_computations
 
 
@@ -36,8 +35,7 @@ def test_scan_flops_multiplied_by_trip_count():
     expected = 2 * n * k * k * trips
     assert parsed["flops_per_device"] == pytest.approx(expected, rel=0.01)
     # and confirm the raw cost_analysis really does NOT multiply
-    # (list on JAX <= 0.4.x, dict on newer -> go through the compat shim)
-    raw = xla_cost_analysis(compiled)["flops"]
+    raw = compiled.cost_analysis()["flops"]
     assert raw < expected / 2
 
 

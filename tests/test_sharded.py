@@ -26,6 +26,7 @@ def _run_sub(code: str, timeout=560) -> str:
 def test_train_step_runs_and_learns_sharded():
     out = _run_sub(r"""
 import jax, jax.numpy as jnp, numpy as np, json
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.launch.steps import make_train_step, init_server_state
@@ -33,7 +34,7 @@ from repro.models import transformer as tr
 from repro.optim import make_optimizer
 from repro.data.tokens import lm_batch
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("qwen2.5-32b", reduced_variant=True)
 shape = InputShape("t", 128, 8, "train")
 bundle = make_train_step(cfg, shape, mesh)
@@ -44,7 +45,7 @@ server = init_server_state(params, mesh=mesh, cfg=cfg)
 step = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                out_shardings=bundle.out_shardings)
 nm = bundle.meta["n_micro"]
-losses = []
+losses, fresh = [], []
 with mesh:
     for t in range(25):
         toks, labels = lm_batch(t % 3, 8, 128, cfg.vocab)  # few repeated batches
@@ -53,17 +54,24 @@ with mesh:
         params, opt_state, server, loss = step(params, opt_state, server,
                                                batch, jnp.asarray(t, jnp.int32))
         losses.append(float(loss))
-# persisted packed server state: flat int8 age buffer, PAD_AGE (-1) pads
-ages = np.concatenate([np.asarray(a).ravel()
-                       for a in jax.tree.leaves(server["age"])])
-valid = ages >= 0
+        # persisted packed server state: flat int8 age buffer, PAD_AGE (-1)
+        # pads; age 0 = selected this round
+        ages = np.asarray(server["age"])
+        fresh.append(float((ages[ages >= 0] == 0).mean()))
 print(json.dumps({"first": losses[0], "last": losses[-1],
-                  "frac_fresh": float((ages[valid] == 0).mean()),
+                  "frac_fresh": float(np.mean(fresh[-12:])),
                   "max_age": int(ages.max())}))
 """)
     res = json.loads(out.strip().splitlines()[-1])
     assert res["last"] < res["first"] - 0.05, res
-    assert 0.05 < res["frac_fresh"] < 0.35, res   # rho = 0.1 target
+    # rho = 0.1 target, as the mean fresh share of the last 12 rounds.  One
+    # round's share swings with the 3-batch cycle (0.04-0.15: the magnitude
+    # threshold comes from the previous round's histogram), so a single
+    # round sat on either side of 0.05 with the numerics of the JAX
+    # release.  The mean is ~0.078 on (1,1), (2,4) and (8,1) meshes alike:
+    # about k_M/d = 0.075, because the age stage is starved (ROADMAP,
+    # Reach: age-stage budget).
+    assert 0.05 < res["frac_fresh"] < 0.35, res
     assert res["max_age"] <= 25, res
 
 
@@ -71,6 +79,7 @@ def test_decode_parity_sharded_vs_single():
     """serve_step on the mesh must match the unsharded decode."""
     out = _run_sub(r"""
 import jax, jax.numpy as jnp, numpy as np, json
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.launch.steps import make_serve_step
@@ -84,7 +93,7 @@ for name in ("qwen2.5-32b", "mamba2-370m", "granite-moe-3b-a800m"):
         0, cfg.vocab, (8, 1)).astype("i4"))
     caches = tr.init_caches(cfg, 8, capacity=64)
     ref_logits, _ = tr.decode_step(params, cfg, toks, jnp.asarray(0), caches)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     bundle = make_serve_step(cfg, InputShape("d", 64, 8, "decode"), mesh)
     with mesh:
         step = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
@@ -107,11 +116,12 @@ def test_fl_oac_collective_reduction():
     (the paper's waveform-budget saving, measured in the compiled HLO)."""
     out = _run_sub(r"""
 import jax, json
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.launch.steps import make_fl_oac_step
 from repro.roofline import analyze_hlo
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("mamba2-370m", reduced_variant=True)
 res = {}
 for base in (False, True):
@@ -132,13 +142,14 @@ def test_fl_oac_step_executes():
     """Run two FL-OAC rounds for real on the 8-device mesh."""
     out = _run_sub(r"""
 import jax, jax.numpy as jnp, numpy as np, json
+from repro.launch.mesh import make_mesh
 from jax.flatten_util import ravel_pytree
 from repro.configs import get_config
 from repro.launch.steps import make_fl_oac_step
 from repro.models import transformer as tr
 from repro.data.tokens import lm_batch
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("mamba2-370m", reduced_variant=True)
 b = make_fl_oac_step(cfg, mesh, seq_len=64, rho=0.1)
 params = tr.init_lm(jax.random.PRNGKey(0), cfg)
@@ -173,6 +184,7 @@ def test_engine_sharded_parity_multi_device():
     global-index jitter property a 1-device parity test cannot see)."""
     out = _run_sub(r"""
 import jax, jax.numpy as jnp, numpy as np, json
+from repro.launch.mesh import make_mesh
 from repro.core.engine import EngineConfig, SelectionEngine
 
 d = 4096
@@ -180,7 +192,7 @@ rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=d).astype("f4"))
 gp = jnp.asarray(rng.normal(size=d).astype("f4"))
 common = dict(policy="fairk", rho=0.1, k_m_frac=0.75, exact_theta=True)
-mesh = jax.make_mesh((8,), ("shard",))
+mesh = make_mesh((8,), ("shard",))
 ex = SelectionEngine(EngineConfig(backend="exact", **common), d)
 th = SelectionEngine(EngineConfig(backend="threshold", **common), d)
 sh = SelectionEngine(EngineConfig(backend="sharded", **common), d,

@@ -1,9 +1,5 @@
 """Property tests for the analytic staleness machinery (satellite).
 
-Runs under the real ``hypothesis`` when installed (CI does); the pinned
-container falls back to ``tests/_hypothesis_compat.py``'s deterministic
-seeded-draw stand-in, so tier-1 stays hermetic either way.
-
 Pins, for ARBITRARY valid parameters (not just the hand-picked operating
 points of the acceptance tests):
 
@@ -22,7 +18,8 @@ import jax
 import numpy as np
 
 import statutil
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core import channel as chan
 from repro.core import faults, markov, population
 

@@ -6,7 +6,12 @@ Four pin families around the chunked client fold in ``fl/trainer.py`` and
 * golden trajectory pins — ``client_chunk=None`` must stay BIT-EXACT with
   the pre-refactor materialise-then-einsum trace for every
   chaos x population x wireless x backend combination
-  (``tests/golden/fl_trajectories.json``, captured before the refactor);
+  (``tests/golden/fl_trajectories.json``).  The pins were captured before
+  the refactor on an older JAX and recaptured on jax 0.9.0, whose default
+  ``jax_threefry_partitionable=True`` draws different random bits: with
+  the old bit stream (``JAX_THREEFRY_PARTITIONABLE=0``) the recapturing
+  tree reproduced 17 of the 19 old pins bit for bit, and the two EF pins
+  to within 2e-6 in the residual alone;
 * the chunk-parity matrix (marked ``streaming``) — chunked runs
   (chunk in {1, 3, N}) match the dense trajectory within float tolerance,
   and chunk == N is bit-exact with ``None`` (same reshape, same trace);
@@ -29,6 +34,7 @@ import flutil
 from repro.core import keys as keys_mod
 from repro.fl import sweep as sweep_mod
 from repro.fl import trainer as fl_trainer
+from repro.launch.mesh import make_mesh
 
 PARITY_TOL = 5e-5     # float reassociation over 3 rounds at D=32
 GOLDENS = flutil.load_goldens()
@@ -235,7 +241,7 @@ def test_launch_chunk_validation():
     from repro.configs.base import InputShape
     from repro.launch.steps import make_train_step
     cfg = get_config("mamba2-370m", reduced_variant=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="client_chunk"):
         make_train_step(cfg, InputShape("t", 64, 4, "train"), mesh,
                         n_micro=4, client_chunk=3)

@@ -3,7 +3,8 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import selection
 from repro.fl.sweep import (SweepConfig, fair_k_mask_dynamic, run_sweep,

@@ -1,0 +1,108 @@
+"""Mosaic lowering of the server-phase Pallas kernels for a TPU v5e.
+
+Interpret mode (every other kernel test) runs the kernel body as plain JAX
+and cannot see the TPU's tiling and layout rules.  These tests compile the
+kernels of the launch path at a real width for a *described* v5e chip —
+the TPU compiler is installed, no chip is attached — and assert the
+compiled program really holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every pytest-xdist
+worker imports this file."""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import packing
+from repro.kernels.fairk_update import (fairk_ef_update_pallas,
+                                        fairk_stats_update_pallas)
+from repro.kernels.sign_mv import sign_from_energy_pallas, sign_mv_pallas
+
+D = 64 * 65536          # 64 grid steps of the production block
+BLOCK = 65536
+D_PACKED = D + 3 * 256  # a packed buffer's length: a partial last block
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU lib"
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _sds(one_chip, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("residual,sanitize", [(False, False), (True, True)],
+                         ids=["plain", "residual-sanitize"])
+@pytest.mark.parametrize("stored", [False, True], ids=["f32", "bf16-int8"])
+def test_fairk_stats_update_lowers(one_chip, residual, sanitize, stored):
+    """``stored``: g_prev and age in the packed server state's dtypes."""
+    stride = packing.hist_stride(D_PACKED)
+    vec = _sds(one_chip, (D_PACKED,))
+    scalar = _sds(one_chip, ())
+    g_prev = _sds(one_chip, (D_PACKED,), jnp.bfloat16) if stored else vec
+    age = _sds(one_chip, (D_PACKED,), jnp.int8) if stored else vec
+
+    def fn(g, gp, age, tm, ta, res):
+        return fairk_stats_update_pallas(
+            g, gp, age, tm, ta, residual=res if residual else None,
+            block_size=BLOCK, stats_stride=stride, sanitize=sanitize)
+
+    _assert_kernel(fn, vec, g_prev, age, scalar, scalar, vec)
+
+
+@pytest.mark.parametrize("stride", [1, 64, 256])
+def test_fairk_stats_update_lowers_every_sample_stride(one_chip, stride):
+    """The histogram sample walks lanes (stride <= 128) or rows (256)."""
+    vec = _sds(one_chip, (D,))
+    scalar = _sds(one_chip, ())
+    _assert_kernel(
+        lambda g, gp, age, tm, ta: fairk_stats_update_pallas(
+            g, gp, age, tm, ta, block_size=BLOCK, stats_stride=stride),
+        vec, vec, vec, scalar, scalar)
+
+
+def test_fairk_ef_update_lowers(one_chip):
+    vec = _sds(one_chip, (D,))
+    scalar = _sds(one_chip, ())
+    _assert_kernel(
+        lambda g, gp, age, tm, ta, res: fairk_ef_update_pallas(
+            g, gp, age, tm, ta, residual=res, block_size=BLOCK),
+        vec, vec, vec, scalar, scalar, vec)
+
+
+def test_sign_mv_lowers(one_chip):
+    _assert_kernel(lambda v: sign_mv_pallas(v, None, block_k=2048),
+                   _sds(one_chip, (1, D)))
+
+
+def test_sign_from_energy_lowers(one_chip):
+    vec = _sds(one_chip, (D,))
+    _assert_kernel(lambda e, z: sign_from_energy_pallas(e, z, block_k=2048),
+                   vec, vec)
