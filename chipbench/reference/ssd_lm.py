@@ -1,0 +1,114 @@
+"""Plain Mamba-2 language model in float32: the reference for SSM configs.
+
+Follows arXiv:2405.21060 as published: per layer an RMSNorm, the input
+projections to z, x, B, C and dt, a causal depthwise convolution with SiLU
+over x and over (B, C), the SSD scan with a per-head scalar decay
+A = -exp(a_log), step dt = softplus(. + dt_bias) and skip D, a gated
+RMSNorm of y * silu(z), and the output projection, added to the residual
+stream.  The scan is the paper's own minimal discrete SSD listing (chunked,
+with the stable masked-cumsum segment sum), at block length 64.  The LM
+head is tied to the embedding.
+
+Every matmul runs at ``Precision.HIGHEST``.  ``quant`` rounds what the
+program holds in its compute dtype: both operands of each matmul, the
+SSD's inputs and the residual stream between layers (the lower-precision
+control); the identity for the reference itself.  Imports nothing of the
+program."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+HI = jax.lax.Precision.HIGHEST
+BLOCK = 64
+
+
+def _mm(x, w, quant):
+    return jnp.einsum("...i,io->...o", quant(x), quant(w), precision=HI)
+
+
+def _rms(x, scale, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+def _conv(x, w, b):
+    """Causal depthwise convolution: y[t] = sum_i w[i] x[t - K + 1 + i]."""
+    k = w.shape[0]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(k))
+    return jax.nn.silu(y + b)
+
+
+def segsum(x):
+    """(..., T) -> (..., T, T): out[i, j] = sum_{j < k <= i} x[k], -inf
+    above the diagonal; summed without differences of cumulative sums."""
+    t = x.shape[-1]
+    xr = jnp.broadcast_to(x[..., :, None], x.shape + (t,))
+    xr = jnp.where(jnp.tril(jnp.ones((t, t), bool), -1), xr, 0.0)
+    s = jnp.cumsum(xr, axis=-2)
+    return jnp.where(jnp.tril(jnp.ones((t, t), bool), 0), s, -jnp.inf)
+
+
+def ssd(x, a, b, c, block=BLOCK):
+    """Minimal discrete SSD.  x (B, L, H, P) already times dt; a (B, L, H)
+    = A * dt; b, c (B, L, H, N).  Returns y (B, L, H, P)."""
+    bsz, l, h, p = x.shape
+    nc = l // block
+    ch = lambda t: t.reshape((bsz, nc, block) + t.shape[2:])
+    x, b, c = ch(x), ch(b), ch(c)
+    a = jnp.moveaxis(ch(a), -1, 1)                             # (B,H,C,L)
+    a_cs = jnp.cumsum(a, -1)
+    lmat = jnp.exp(segsum(a))                                  # (B,H,C,L,L)
+    y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp", c, b, lmat, x,
+                        precision=HI)
+    decay_states = jnp.exp(a_cs[..., -1:] - a_cs)
+    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", b, decay_states, x,
+                        precision=HI)
+    states = jnp.concatenate([jnp.zeros_like(states[:, :1]), states], 1)
+    decay_chunk = jnp.exp(segsum(jnp.pad(a_cs[..., -1], ((0, 0), (0, 0),
+                                                         (1, 0)))))
+    states = jnp.einsum("bhzc,bchpn->bzhpn", decay_chunk, states,
+                        precision=HI)[:, :-1]
+    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", c, states, jnp.exp(a_cs),
+                       precision=HI)
+    return (y_diag + y_off).reshape(bsz, l, h, p)
+
+
+def _layer(x, p, m, quant):
+    eps = m["norm_eps"]
+    d_in = m["ssm_expand"] * m["d_model"]
+    n, hd = m["ssm_state"], m["ssm_head_dim"]
+    heads, g = d_in // hd, m["ssm_groups"]
+    mx = p["mixer"]
+    hn = _rms(x, p["norm1"]["scale"], eps)
+    z = _mm(hn, mx["wz"]["w"], quant)
+    xc = _conv(_mm(hn, mx["wx"]["w"], quant), mx["conv_x_w"], mx["conv_x_b"])
+    bc = _conv(_mm(hn, mx["wbc"]["w"], quant), mx["conv_bc_w"],
+               mx["conv_bc_b"])
+    dt = jax.nn.softplus(_mm(hn, mx["wdt"]["w"], quant) + mx["dt_bias"])
+    a = -jnp.exp(mx["a_log"])
+    bsz, l = x.shape[:2]
+    rep = lambda t: jnp.repeat(t.reshape(bsz, l, g, n), heads // g, axis=2)
+    bm, cm = rep(bc[..., :g * n]), rep(bc[..., g * n:])
+    xh = xc.reshape(bsz, l, heads, hd)
+    y = ssd(quant(xh * dt[..., None]), a * dt, quant(bm), quant(cm))
+    y = (y + mx["d_skip"][:, None] * xh).reshape(bsz, l, d_in)
+    y = _rms(y * jax.nn.silu(z), mx["norm"]["scale"], eps)
+    return quant(x + _mm(y, mx["out_proj"]["w"], quant))
+
+
+def loss(params, tokens, labels, m, quant=lambda t: t):
+    """Mean next-token cross entropy over a (B, L) batch."""
+    x = quant(params["embed"][tokens])
+    block = params["blocks"][0]
+
+    def body(x, p):
+        return jax.checkpoint(lambda x_, p_: _layer(x_, p_, m, quant))(x, p), None
+
+    x, _ = jax.lax.scan(body, x, block)
+    x = _rms(x, params["final_norm"]["scale"], m["norm_eps"])
+    logits = jnp.einsum("bld,vd->blv", quant(x), quant(params["embed"]),
+                        precision=HI)
+    logp = jax.nn.log_softmax(logits, -1)
+    return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], -1))
