@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import packing, selection
 
 Array = jax.Array
@@ -601,24 +602,26 @@ class SelectionEngine:
                 f"sanitize runs selection in threshold/rank form — policy "
                 f"{self.cfg.policy!r} needs index arithmetic; choose from "
                 f"{THRESHOLD_POLICIES}")
-        if erase is not None:
-            # one degradation path for both fault channels: erased
-            # coordinates become NaN scores and ride the sanitize stage
-            g = jnp.where(jnp.asarray(erase) > 0.0, jnp.float32(jnp.nan),
-                          g.astype(jnp.float32))
-        backend = self.cfg.backend
-        if backend == "exact":
-            return self._exact_update(g, g_prev, age, key, residual, fresh,
-                                      k_m_frac, age_lag, sanitize)
-        if backend == "threshold":
-            return self._threshold_update(g, g_prev, age, key, residual,
-                                          fresh, k_m_frac, age_lag, sanitize)
-        if backend == "packed":
-            return self._packed_update(g, g_prev, age, key, tstate,
-                                       residual, fresh, k_m_frac, age_lag,
-                                       sanitize)
-        return self._sharded_update(g, g_prev, age, key, residual, fresh,
-                                    tstate, k_m_frac, age_lag, sanitize)
+        with obs.scope("fairk"):
+            if erase is not None:
+                # one degradation path for both fault channels: erased
+                # coordinates become NaN scores and ride the sanitize stage
+                g = jnp.where(jnp.asarray(erase) > 0.0, jnp.float32(jnp.nan),
+                              g.astype(jnp.float32))
+            backend = self.cfg.backend
+            if backend == "exact":
+                return self._exact_update(g, g_prev, age, key, residual, fresh,
+                                          k_m_frac, age_lag, sanitize)
+            if backend == "threshold":
+                return self._threshold_update(g, g_prev, age, key, residual,
+                                              fresh, k_m_frac, age_lag,
+                                              sanitize)
+            if backend == "packed":
+                return self._packed_update(g, g_prev, age, key, tstate,
+                                           residual, fresh, k_m_frac, age_lag,
+                                           sanitize)
+            return self._sharded_update(g, g_prev, age, key, residual, fresh,
+                                        tstate, k_m_frac, age_lag, sanitize)
 
     def _noisy(self, fresh: Array, key: Optional[Array]) -> Array:
         cfg = self.cfg

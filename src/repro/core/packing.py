@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 Array = jax.Array
 
 # Age sentinel marking pad coordinates.  Real AoU values are >= 0; -1 fits
@@ -133,13 +135,14 @@ class PackedLayout:
         PACK_CALLS += 1
         leaves = self.treedef.flatten_up_to(tree)
         parts = []
-        for e, leaf in zip(self.table, leaves):
-            parts.append(jnp.asarray(leaf).reshape(-1).astype(dtype))
-            if e.pad:
-                parts.append(jnp.full((e.pad,), fill, dtype))
-        if len(parts) == 1:
-            return parts[0]
-        return jnp.concatenate(parts)
+        with obs.scope("pack"):
+            for e, leaf in zip(self.table, leaves):
+                parts.append(jnp.asarray(leaf).reshape(-1).astype(dtype))
+                if e.pad:
+                    parts.append(jnp.full((e.pad,), fill, dtype))
+            if len(parts) == 1:
+                return parts[0]
+            return jnp.concatenate(parts)
 
     def pack_age(self, tree: Any, dtype=jnp.float32) -> Array:
         """Age tree -> flat buffer with PAD_AGE sentinel in the pads."""
@@ -150,10 +153,12 @@ class PackedLayout:
         global UNPACK_CALLS
         UNPACK_CALLS += 1
         out = []
-        for e in self.table:
-            leaf = jax.lax.slice(flat, (e.offset,), (e.offset + e.size,))
-            leaf = leaf.reshape(e.shape)
-            out.append(leaf.astype(e.dtype) if cast else leaf)
+        with obs.scope("unpack"):
+            for e in self.table:
+                leaf = jax.lax.slice(flat, (e.offset,),
+                                     (e.offset + e.size,))
+                leaf = leaf.reshape(e.shape)
+                out.append(leaf.astype(e.dtype) if cast else leaf)
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
     # -- pad bookkeeping ----------------------------------------------------

@@ -66,6 +66,10 @@ Array = jax.Array
 LANES = 128
 BLOCK_QUANTUM = 8 * LANES
 
+# the kernel's name in the compiled program and in a profiler trace (both
+# variants: with and without the statistics tile)
+KERNEL_NAME = "fairk_update"
+
 # per-block stats tile (f32, one (STATS_ROWS, LANES) tile per grid step):
 # row STATS_COUNT_ROW holds [n_sel, n_sel_m, 0, ...], rows STATS_MAG_ROW /
 # STATS_AGE_ROW the strided-sample magnitude / age histograms (one bin per
@@ -295,6 +299,7 @@ def _fairk_call(g, g_prev, age, theta_m, theta_a, *, residual, fresh,
                                               jnp.float32))
     out = pl.pallas_call(
         kernel,
+        name=KERNEL_NAME,
         grid=(nb,),
         in_specs=in_specs,
         out_specs=out_specs,

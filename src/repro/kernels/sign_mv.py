@@ -74,6 +74,7 @@ def sign_from_energy_pallas(energy: Array, noise: Optional[Array] = None,
             else (energy.astype(jnp.float32), noise.astype(jnp.float32)))
     signs, energy_out = pl.pallas_call(
         kernel,
+        name="sign_from_energy",
         grid=(nb,),
         in_specs=in_specs,
         out_specs=[vec_spec, vec_spec],
@@ -100,6 +101,7 @@ def sign_mv_pallas(votes: Array, noise: Optional[Array] = None,
             else (votes.astype(jnp.float32), noise.astype(jnp.float32)))
     signs, energy = pl.pallas_call(
         kernel,
+        name="sign_mv",
         grid=(nb,),
         in_specs=in_specs,
         out_specs=[vec_spec, vec_spec],

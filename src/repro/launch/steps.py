@@ -38,6 +38,7 @@ from math import prod as np_prod
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import InputShape, ModelConfig
 from repro.core import channel as chan
 from repro.core import controller as budget
@@ -334,6 +335,7 @@ def _mesh_devices(mesh) -> int:
     return n
 
 
+@obs.span("server_init")
 def init_server_state(params: Any, mesh=None, cfg: ModelConfig = None,
                       oac: Optional[OacServerConfig] = OacServerConfig()
                       ) -> Dict:
@@ -603,130 +605,134 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
                              fused_stats=oac.fused_stats,
                              reduce_axes=mesh_axes),
                 layout.d_packed, layout=layout)
-            tstate = packing.threshold_state_from_vec(server["theta"])
-            cstate = kmf = None
-            if oac.adaptive_km:
-                # the live split comes off the carried controller state —
-                # replicated across shards (its inputs are the pmean'd
-                # histograms, so every shard computes the same successor)
-                cstate = budget.controller_state_from_vec(server["ctrl"])
-                kmf = cstate["k_m_frac"]
-            key = _shard_noise_key(seed) if oac.noise_std > 0.0 else None
-            pop_stats = None
-            if oac.population is not None:
-                # stateless population round (DESIGN.md §15): iid/diurnal
-                # chains are memoryless, so the round's availability grid
-                # is a pure counter-based function of (base key, seed) —
-                # no chain state rides the checkpointed server buffers,
-                # and consecutive round seeds walk a lawful trajectory.
-                # Replicated computation: no shard fold-in, so every
-                # shard derives identical round stats (no collective).
-                pop_stats = pop_mod.stateless_round(
-                    jax.random.PRNGKey(0x509), seed, oac.population)
+            with obs.scope("fairk"):
+                tstate = packing.threshold_state_from_vec(server["theta"])
+            with obs.scope("server_stages"):
+                cstate = kmf = None
+                if oac.adaptive_km:
+                    # the live split comes off the carried controller state —
+                    # replicated across shards (its inputs are the pmean'd
+                    # histograms, so every shard computes the same successor)
+                    cstate = budget.controller_state_from_vec(server["ctrl"])
+                    kmf = cstate["k_m_frac"]
+                key = _shard_noise_key(seed) if oac.noise_std > 0.0 else None
+                pop_stats = None
+                if oac.population is not None:
+                    # stateless population round (DESIGN.md §15): iid/diurnal
+                    # chains are memoryless, so the round's availability grid
+                    # is a pure counter-based function of (base key, seed) —
+                    # no chain state rides the checkpointed server buffers,
+                    # and consecutive round seeds walk a lawful trajectory.
+                    # Replicated computation: no shard fold-in, so every
+                    # shard derives identical round stats (no collective).
+                    pop_stats = pop_mod.stateless_round(
+                        jax.random.PRNGKey(0x509), seed, oac.population)
             g_flat = layout.pack(grads)            # the ONLY pack per step
-            new_fad = wl_erase = None
-            if oac.wireless is not None:
-                # aggregate-equivalent wireless round (DESIGN.md §16):
-                # advance this shard's per-block AR(1) fading chains and
-                # mark the blocks whose gain misses the threshold
-                # calibrated to the truncation-outage rate (the erasure
-                # composes into the sanitize path below); imperfect CSI
-                # multiplies the fresh aggregate by the per-block
-                # misalignment factor.  Per-shard draws (disjoint
-                # coordinate slices => the global pattern), decorrelated
-                # from the noise/fade/churn streams by distinct fold-ins;
-                # everything elementwise — G_READS stays 1.
-                new_fad, wl_erase = chan.block_outage(
-                    server["fad"],
-                    jax.random.fold_in(_shard_noise_key(seed), 0xC4A),
-                    layout.d_packed, oac.wireless)
-                g_flat = g_flat * chan.csi_block_factor(
-                    jax.random.fold_in(_shard_noise_key(seed), 0xC51),
-                    layout.d_packed, oac.wireless)
-            age_lag = None
-            new_shadow = None
-            if oac.async_agg:
-                # straggler OAC contributions land one aggregation late: a
-                # Knuth-hash pattern of coordinates defers its share of
-                # THIS round's uplink into the shadow buffer while LAST
-                # round's shadow joins the merge.  Elementwise mixing on
-                # the packed buffer — not an extra instrumented read of
-                # the persisted gradient state, so G_READS stays 1.  With
-                # a population the threshold is the round's TRACED
-                # straggler share (sampled from the live cohort) instead
-                # of the fixed ``straggler_frac`` — same hash pattern,
-                # data-dependent coverage, still zero recompiles.
-                frac = (pop_stats["slow_share"]
-                        if oac.population is not None
-                        else oac.straggler_frac)
-                strag = (index_jitter(layout.d_packed)
-                         < frac).astype(jnp.float32)
-                new_shadow = g_flat * strag
-                g_flat = (g_flat * (1.0 - strag)
-                          + server["shadow"].astype(jnp.float32))
-                age_lag = oac.straggler_lag
-            fresh = None
-            if oac.one_bit:
-                # one-bit uplink: the transmitted values are the SIGNS of
-                # the effective gradient, detected by the sign_mv kernel
-                # from the (noisy) energy — with EF the sign is taken on
-                # score = g + residual, the same fold the fused kernel
-                # applies, so residual' = score - mask*sign accumulates
-                # the quantization error.  Channel noise rides the vote
-                # energy (engine noise off), like the FL sim's route.
-                from repro.kernels import ops
-                eff = g_flat
-                if "res" in server:
-                    eff = eff + server["res"]
-                # unscaled sigma_z on the superposed energy — the same
-                # convention as the FL sim's one-bit route (the noise
-                # perturbs the detection statistic once; it does NOT
-                # average down over clients like the coherent channel)
-                noise = (oac.noise_std
-                         * jax.random.normal(_shard_noise_key(seed),
-                                             g_flat.shape, jnp.float32)
-                         if oac.noise_std > 0.0 else None)
-                fresh, _ = ops.sign_mv(eff[None, :], noise=noise)
-                key = None
-            erase = None
-            if oac.fade > 0.0:
-                # deep-fade block erasures on the aggregated signal: a
-                # per-shard draw (each shard owns a disjoint coordinate
-                # slice, so independent per-shard masks ARE the global
-                # mask), decorrelated from the channel-noise stream by a
-                # fold-in.  The engine converts erased coordinates to NaN
-                # and the sanitize stage keeps them out of selection.
-                erase = faults.fade_mask(
-                    jax.random.fold_in(_shard_noise_key(seed), 0xFADE),
-                    layout.d_packed,
-                    faults.FaultConfig(fade=oac.fade,
-                                       fade_block=oac.fade_block))
-            if oac.population is not None:
-                # mid-round churn erasure (DESIGN.md §15): symbol blocks
-                # lost to participants whose chain dropped mid-round, at
-                # the round's traced churn rate; a TOTAL cohort outage
-                # erases everything.  Per-shard draw (disjoint slices =>
-                # the global mask), decorrelated from the fade stream.
-                churn_er = faults.erase_with_outage(
-                    pop_mod.churn_erase_mask(
-                        jax.random.fold_in(_shard_noise_key(seed), 0x509),
-                        layout.d_packed, pop_stats["churn"],
-                        oac.population),
-                    pop_stats["n_t"])
-                erase = (churn_er if erase is None
-                         else jnp.maximum(erase, churn_er))
-            if wl_erase is not None:
-                erase = (wl_erase if erase is None
-                         else jnp.maximum(erase, wl_erase))
+            with obs.scope("server_stages"):
+                new_fad = wl_erase = None
+                if oac.wireless is not None:
+                    # aggregate-equivalent wireless round (DESIGN.md §16):
+                    # advance this shard's per-block AR(1) fading chains and
+                    # mark the blocks whose gain misses the threshold
+                    # calibrated to the truncation-outage rate (the erasure
+                    # composes into the sanitize path below); imperfect CSI
+                    # multiplies the fresh aggregate by the per-block
+                    # misalignment factor.  Per-shard draws (disjoint
+                    # coordinate slices => the global pattern), decorrelated
+                    # from the noise/fade/churn streams by distinct fold-ins;
+                    # everything elementwise — G_READS stays 1.
+                    new_fad, wl_erase = chan.block_outage(
+                        server["fad"],
+                        jax.random.fold_in(_shard_noise_key(seed), 0xC4A),
+                        layout.d_packed, oac.wireless)
+                    g_flat = g_flat * chan.csi_block_factor(
+                        jax.random.fold_in(_shard_noise_key(seed), 0xC51),
+                        layout.d_packed, oac.wireless)
+                age_lag = None
+                new_shadow = None
+                if oac.async_agg:
+                    # straggler OAC contributions land one aggregation late: a
+                    # Knuth-hash pattern of coordinates defers its share of
+                    # THIS round's uplink into the shadow buffer while LAST
+                    # round's shadow joins the merge.  Elementwise mixing on
+                    # the packed buffer — not an extra instrumented read of
+                    # the persisted gradient state, so G_READS stays 1.  With
+                    # a population the threshold is the round's TRACED
+                    # straggler share (sampled from the live cohort) instead
+                    # of the fixed ``straggler_frac`` — same hash pattern,
+                    # data-dependent coverage, still zero recompiles.
+                    frac = (pop_stats["slow_share"]
+                            if oac.population is not None
+                            else oac.straggler_frac)
+                    strag = (index_jitter(layout.d_packed)
+                             < frac).astype(jnp.float32)
+                    new_shadow = g_flat * strag
+                    g_flat = (g_flat * (1.0 - strag)
+                              + server["shadow"].astype(jnp.float32))
+                    age_lag = oac.straggler_lag
+                fresh = None
+                if oac.one_bit:
+                    # one-bit uplink: the transmitted values are the SIGNS of
+                    # the effective gradient, detected by the sign_mv kernel
+                    # from the (noisy) energy — with EF the sign is taken on
+                    # score = g + residual, the same fold the fused kernel
+                    # applies, so residual' = score - mask*sign accumulates
+                    # the quantization error.  Channel noise rides the vote
+                    # energy (engine noise off), like the FL sim's route.
+                    from repro.kernels import ops
+                    eff = g_flat
+                    if "res" in server:
+                        eff = eff + server["res"]
+                    # unscaled sigma_z on the superposed energy — the same
+                    # convention as the FL sim's one-bit route (the noise
+                    # perturbs the detection statistic once; it does NOT
+                    # average down over clients like the coherent channel)
+                    noise = (oac.noise_std
+                             * jax.random.normal(_shard_noise_key(seed),
+                                                 g_flat.shape, jnp.float32)
+                             if oac.noise_std > 0.0 else None)
+                    fresh, _ = ops.sign_mv(eff[None, :], noise=noise)
+                    key = None
+                erase = None
+                if oac.fade > 0.0:
+                    # deep-fade block erasures on the aggregated signal: a
+                    # per-shard draw (each shard owns a disjoint coordinate
+                    # slice, so independent per-shard masks ARE the global
+                    # mask), decorrelated from the channel-noise stream by a
+                    # fold-in.  The engine converts erased coordinates to NaN
+                    # and the sanitize stage keeps them out of selection.
+                    erase = faults.fade_mask(
+                        jax.random.fold_in(_shard_noise_key(seed), 0xFADE),
+                        layout.d_packed,
+                        faults.FaultConfig(fade=oac.fade,
+                                           fade_block=oac.fade_block))
+                if oac.population is not None:
+                    # mid-round churn erasure (DESIGN.md §15): symbol blocks
+                    # lost to participants whose chain dropped mid-round, at
+                    # the round's traced churn rate; a TOTAL cohort outage
+                    # erases everything.  Per-shard draw (disjoint slices =>
+                    # the global mask), decorrelated from the fade stream.
+                    churn_er = faults.erase_with_outage(
+                        pop_mod.churn_erase_mask(
+                            jax.random.fold_in(_shard_noise_key(seed), 0x509),
+                            layout.d_packed, pop_stats["churn"],
+                            oac.population),
+                        pop_stats["n_t"])
+                    erase = (churn_er if erase is None
+                             else jnp.maximum(erase, churn_er))
+                if wl_erase is not None:
+                    erase = (wl_erase if erase is None
+                             else jnp.maximum(erase, wl_erase))
             g_t, age_next, stats = eng.select_and_merge(
                 g_flat, server["g"], server["age"], key=key, tstate=tstate,
                 residual=server.get("res"), fresh=fresh, k_m_frac=kmf,
                 age_lag=age_lag, erase=erase, sanitize=oac.sanitize)
-            new_server = {
-                "g": g_t.astype(jnp.bfloat16),
-                "age": age_next.astype(jnp.int8),
-                "theta": packing.threshold_state_to_vec(stats["tstate"]),
-            }
+            with obs.scope("server_cast"):
+                new_server = {
+                    "g": g_t.astype(jnp.bfloat16),
+                    "age": age_next.astype(jnp.int8),
+                    "theta": packing.threshold_state_to_vec(stats["tstate"]),
+                }
             if "res" in server:
                 new_server["res"] = stats["residual"]
             if oac.wireless is not None:
@@ -734,9 +740,11 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
             if oac.adaptive_km:
                 # in-graph controller step off the (pmean'd) kernel
                 # histograms — the same compiled program at every split
-                cstate = bctrl.update(cstate, stats["age_hist"],
-                                      stats["mag_hist"])
-                new_server["ctrl"] = budget.controller_state_to_vec(cstate)
+                with obs.scope("server_stages"):
+                    cstate = bctrl.update(cstate, stats["age_hist"],
+                                          stats["mag_hist"])
+                    new_server["ctrl"] = budget.controller_state_to_vec(
+                        cstate)
             if oac.async_agg:
                 # double-buffer swap: the optimizer consumes the PREVIOUS
                 # round's merged gradient, so this round's fused pass has
@@ -744,9 +752,10 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
                 # next round's client compute.  Round 0's pending buffer
                 # is zeros (a no-op update), matching the one-round
                 # pipeline fill.
-                new_server["shadow"] = new_shadow.astype(jnp.bfloat16)
-                new_server["pending"] = g_t.astype(jnp.bfloat16)
-                out = server["pending"].astype(jnp.float32)
+                with obs.scope("server_cast"):
+                    new_server["shadow"] = new_shadow.astype(jnp.bfloat16)
+                    new_server["pending"] = g_t.astype(jnp.bfloat16)
+                    out = server["pending"].astype(jnp.float32)
             else:
                 out = g_t
             # the optimizer consumes per-leaf trees: ONE unpack per step
@@ -780,10 +789,12 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
             phase = (_packed_server_phase if oac.packed
                      else _per_leaf_server_phase)
             g_t, new_server = phase(server, grads, seed)
-            g_t = jax.tree.map(lambda gt, p: gt.astype(p.dtype), g_t, params)
-            updates, new_opt = opt.update(g_t, opt_state, params)
-            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                      params, updates)
+            with obs.scope("adamw"):
+                g_t = jax.tree.map(lambda gt, p: gt.astype(p.dtype), g_t,
+                                   params)
+                updates, new_opt = opt.update(g_t, opt_state, params)
+                new_params = jax.tree.map(
+                    lambda p, u: p + u.astype(p.dtype), params, updates)
             return new_params, new_opt, new_server
 
         update_sharded = jax.shard_map(
@@ -792,12 +803,15 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
             out_specs=(p_specs, o_specs, srv_specs), check_vma=False)
     else:
         def update_sharded(params, opt_state, server, grads, seed):
-            updates, new_opt = opt.update(grads, opt_state, params)
-            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                      params, updates)
+            with obs.scope("adamw"):
+                updates, new_opt = opt.update(grads, opt_state, params)
+                new_params = jax.tree.map(
+                    lambda p, u: p + u.astype(p.dtype), params, updates)
             return new_params, new_opt, server
 
-    def train_step(params, opt_state, server, batch, seed):
+    def client_phase(params, batch):
+        """The clients' forward and backward over the round's microbatches:
+        (mean loss, mean gradient in the parameters' dtypes)."""
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                              params)
         if gather_dtype is not None:
@@ -844,6 +858,11 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
         loss = loss / n_micro
         grads = jax.tree.map(lambda g, p: (g / n_micro).astype(p.dtype),
                              grads, params)
+        return loss, grads
+
+    def train_step(params, opt_state, server, batch, seed):
+        with obs.scope("client"):
+            loss, grads = client_phase(params, batch)
         new_params, new_opt, new_server = update_sharded(
             params, opt_state, server, grads, seed)
         return new_params, new_opt, new_server, loss

@@ -34,8 +34,9 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation
 
-from repro import checkpoint
+from repro import checkpoint, obs
 from repro.configs import ARCHS, get_config
 from repro.configs.base import InputShape
 from repro.core import packing
@@ -47,6 +48,8 @@ from repro.launch.steps import (OacServerConfig, init_server_state,
 from repro.models import transformer as tr
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
+# the host spans of one step of ``run``, in the order they run
+HOST_PHASES = ("batch", "dispatch", "block", "readback", "ckpt")
 
 
 def enable_compile_cache() -> str:
@@ -230,7 +233,10 @@ def run(args) -> dict:
     around each step up to ``block_until_ready``), ``sel_frac`` (the
     share of this shard's coordinates the server phase selected, from the
     fused kernel's counts carried in the threshold state; None without the
-    packed phase) and the ``compiled`` step."""
+    packed phase), ``host_ms`` (for each of ``HOST_PHASES`` the ms of its
+    host span in each step: building the batch, dispatching the step,
+    waiting for the device, reading ``sel_frac`` back, checkpointing; 0
+    where a step saved nothing) and the ``compiled`` step."""
     cfg = get_config(args.arch, reduced_variant=args.reduced)
     data, model = parse_mesh(args.mesh)
     if data * model > len(jax.devices()):
@@ -385,7 +391,8 @@ def run(args) -> dict:
     d_valid = (server_layout(params, shlib.param_pspecs(params, cfg, mesh),
                              mesh).d_valid
                if oac is not None and oac.packed else None)
-    out = {"losses": [], "step_s": [], "sel_frac": []}
+    out = {"losses": [], "step_s": [], "sel_frac": [],
+           "host_ms": {phase: [] for phase in HOST_PHASES}}
     prev_term = signal.signal(signal.SIGTERM, _on_term)
     try:
         with mesh:
@@ -398,27 +405,41 @@ def run(args) -> dict:
             print(f"[train] compiled the step in {out['compile_s']:.1f}s",
                   flush=True)
             for t in range(start, start + args.steps):
-                batch = make_batch(t)
-                t0 = time.perf_counter()
-                seed = jnp.asarray(t, jnp.int32)
-                params, opt_state, server, loss = compiled(
-                    params, opt_state, server, batch, seed)
-                jax.block_until_ready((params, opt_state, server, loss))
-                dt = time.perf_counter() - t0
-                out["losses"].append(float(loss))
-                out["step_s"].append(dt)
-                sel = None
-                if d_valid:
-                    n_sel = np.asarray(server["theta"])[
-                        packing.THRESHOLD_STATE_FIELDS.index("n_sel")]
-                    sel = float(n_sel) / d_valid
-                out["sel_frac"].append(sel)
-                print(f"  step {t:3d} loss {out['losses'][-1]:.4f} ({dt:.2f}s)"
-                      + (f" selected {sel:.4f}" if sel is not None else ""),
-                      flush=True)
-                if ckpt_on and args.ckpt_every > 0 and (
-                        (t + 1 - start) % args.ckpt_every == 0):
-                    save(t + 1)
+                spans = {}
+                with StepTraceAnnotation("train_step", step_num=t):
+                    with obs.span("batch") as spans["batch"]:
+                        batch = make_batch(t)
+                    t0 = time.perf_counter()
+                    with obs.span("dispatch") as spans["dispatch"]:
+                        seed = jnp.asarray(t, jnp.int32)
+                        params, opt_state, server, loss = compiled(
+                            params, opt_state, server, batch, seed)
+                    with obs.span("block") as spans["block"]:
+                        jax.block_until_ready((params, opt_state, server,
+                                               loss))
+                    dt = time.perf_counter() - t0
+                    out["losses"].append(float(loss))
+                    out["step_s"].append(dt)
+                    sel = None
+                    with obs.span("readback") as spans["readback"]:
+                        if d_valid:
+                            n_sel = np.asarray(server["theta"])[
+                                packing.THRESHOLD_STATE_FIELDS.index(
+                                    "n_sel")]
+                            sel = float(n_sel) / d_valid
+                    out["sel_frac"].append(sel)
+                    print(f"  step {t:3d} loss {out['losses'][-1]:.4f} "
+                          f"({dt:.2f}s, batch {spans['batch'].ms:.1f} ms, "
+                          f"block {spans['block'].ms:.1f} ms)"
+                          + (f" selected {sel:.4f}" if sel is not None
+                             else ""), flush=True)
+                    if ckpt_on and args.ckpt_every > 0 and (
+                            (t + 1 - start) % args.ckpt_every == 0):
+                        with obs.span("ckpt") as spans["ckpt"]:
+                            save(t + 1)
+                for phase in HOST_PHASES:
+                    out["host_ms"][phase].append(
+                        spans[phase].ms if phase in spans else 0.0)
                 if stop["sig"]:
                     if ckpt_on:
                         save(t + 1)

@@ -6,6 +6,7 @@ import signal
 
 import pytest
 
+from repro import obs
 from repro.launch import train
 from repro.launch.mesh import parse_mesh
 
@@ -35,6 +36,33 @@ def test_run_reports_each_step():
     # selected; then the warm thresholds hold it near rho = 0.1
     assert out["sel_frac"][0] == 1.0
     assert all(0.0 < s <= 0.5 for s in out["sel_frac"][1:])
+    # the host spans of each step: every phase, one entry per step
+    assert tuple(out["host_ms"]) == train.HOST_PHASES
+    assert all(len(v) == 3 and all(x >= 0.0 for x in v)
+               for v in out["host_ms"].values())
+    assert out["host_ms"]["ckpt"] == [0.0] * 3       # no --ckpt-every
+    assert all(b > 0.0 for b in out["host_ms"]["block"])
+
+
+def test_run_times_each_step_by_host_span(tmp_path):
+    obs.reset()
+    out = train.run(train.parse_args(
+        ["--arch", "mamba2-370m", "--steps", "2", "--batch", "2",
+         "--seq", "32", "--ckpt-every", "1", "--ckpt-dir", str(tmp_path)]))
+    spans = obs.spans()
+    assert [s.name for s in spans if s.name == "server_init"] == [
+        "server_init"]
+    steps = [s for s in spans if s.name in train.HOST_PHASES]
+    assert [s.name for s in steps] == list(train.HOST_PHASES) * 2
+    assert all(s.parent is None for s in steps)
+    for i, phase in enumerate(train.HOST_PHASES):
+        assert out["host_ms"][phase] == [steps[i].ms,
+                                         steps[i + len(train.HOST_PHASES)].ms]
+    assert all(x > 0.0 for x in out["host_ms"]["ckpt"])
+    # dispatch and block are the two spans inside step_s
+    assert all(a + b <= s * 1e3 + 1.0 for a, b, s in zip(
+        out["host_ms"]["dispatch"], out["host_ms"]["block"], out["step_s"]))
+    obs.reset()
 
 
 def test_run_rejects_a_mesh_larger_than_the_host():

@@ -13,12 +13,14 @@ worker imports this file."""
 from __future__ import annotations
 
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.core import packing
 from repro.kernels.fairk_update import (fairk_ef_update_pallas,
                                         fairk_stats_update_pallas)
@@ -106,3 +108,22 @@ def test_sign_from_energy_lowers(one_chip):
     vec = _sds(one_chip, (D,))
     _assert_kernel(lambda e, z: sign_from_energy_pallas(e, z, block_k=2048),
                    vec, vec)
+
+
+def test_scope_table_of_the_step_for_the_chip(one_chip, monkeypatch):
+    """Every scope owns work of its own in the chip's compile of a reduced
+    full-server train step (the CPU fuses two of them away)."""
+    from test_obs import compile_step
+    # the host is a CPU: steer the step onto its chip path (Pallas kernels)
+    monkeypatch.setattr(sys.modules["repro.kernels.ops"], "_on_tpu",
+                        lambda: True)
+    compiled = compile_step(devices=list(one_chip.device_set))
+    assert "fairk_update" in compiled.as_text()
+    sets = obs.scope_sets(compiled)
+    table = obs.scope_table(compiled)
+    none = sorted((k, sorted(map(str, s))) for k, s in sets.items()
+                  if table[k] is None and s)
+    assert set(table.values()) >= set(obs.SCOPES), (
+        f"scopes with no instruction of their own: "
+        f"{set(obs.SCOPES) - set(table.values())}; top-level instructions "
+        f"with None: {none}")
