@@ -256,14 +256,14 @@ def build_async_fn(tree, *, rho=0.1, straggler_frac=0.25, straggler_lag=1):
         g_t, age_next, stats = eng.select_and_merge(
             g_flat, gp_flat, age_flat, tstate=tstate,
             age_lag=straggler_lag)
-        out_tree = layout.unpack(pending.astype(jnp.float32), cast=False)
+        out_tree = layout.unpack(pending, cast=False)
         return (out_tree, g_t.astype(jnp.bfloat16),
                 age_next.astype(jnp.int8), stats["tstate"],
                 new_shadow, g_t.astype(jnp.bfloat16))
 
     def critical_path(pending):
         # exactly the slice of the round the optimizer must wait for
-        return layout.unpack(pending.astype(jnp.float32), cast=False)
+        return layout.unpack(pending, cast=False)
 
     return jax.jit(async_round), jax.jit(critical_path), layout
 

@@ -81,6 +81,24 @@ UNPACK_CALLS = 0
 G_READS = 0
 
 
+def _relayout(slot: Array, shape: Tuple[int, ...]) -> Array:
+    """One leaf's 1-D slot -> the leaf's shape, as its own op.
+
+    A rank-2+ leaf is a real relayout on the TPU (the flat buffer's
+    1024-element tiles become the leaf's (8, 128) tiles).  Left to
+    itself, XLA rewrites ``reshape(slice(buffer))`` into
+    ``slice(reshape(buffer))`` wherever the slot's bounds are multiples of
+    the leaf's minor width, relaying the WHOLE buffer once per such width
+    to pick a few leaves out of it; and it hoists the consumer's
+    elementwise work (AdamW's two scalings of g) above the reshape,
+    relaying each scaled copy.  The barriers pin the slot, then the
+    relaid leaf, so each leaf is relaid exactly once."""
+    if len(shape) < 2:
+        return slot.reshape(shape)
+    slot = jax.lax.optimization_barrier(slot)
+    return jax.lax.optimization_barrier(slot.reshape(shape))
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockEntry:
     """One leaf's slot in the packed buffer (static metadata)."""
@@ -149,7 +167,12 @@ class PackedLayout:
         return self.pack(tree, dtype=dtype, fill=PAD_AGE)
 
     def unpack(self, flat: Array, cast: bool = True) -> Any:
-        """(d_packed,) buffer -> tree of original shapes (static slices)."""
+        """(d_packed,) buffer -> tree of original shapes (static slices).
+
+        Each leaf's bytes move once: its slot is sliced out of the buffer
+        (and cast to the leaf's dtype with ``cast``), and only then relaid
+        to the leaf's shape.  ``cast=False`` keeps the buffer's dtype, so a
+        consumer that widens it reads the narrow slot."""
         global UNPACK_CALLS
         UNPACK_CALLS += 1
         out = []
@@ -157,8 +180,9 @@ class PackedLayout:
             for e in self.table:
                 leaf = jax.lax.slice(flat, (e.offset,),
                                      (e.offset + e.size,))
-                leaf = leaf.reshape(e.shape)
-                out.append(leaf.astype(e.dtype) if cast else leaf)
+                if cast:
+                    leaf = leaf.astype(e.dtype)
+                out.append(_relayout(leaf, e.shape))
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
     # -- pad bookkeeping ----------------------------------------------------

@@ -755,10 +755,12 @@ def make_train_step(cfg: ModelConfig, shape: InputShape, mesh, *,
                 with obs.scope("server_cast"):
                     new_server["shadow"] = new_shadow.astype(jnp.bfloat16)
                     new_server["pending"] = g_t.astype(jnp.bfloat16)
-                    out = server["pending"].astype(jnp.float32)
+                out = server["pending"]
             else:
                 out = g_t
-            # the optimizer consumes per-leaf trees: ONE unpack per step
+            # the optimizer consumes per-leaf trees: ONE unpack per step, in
+            # the buffer's dtype (AdamW widens bf16 ``pending`` leaf by leaf
+            # as it reads it)
             return layout.unpack(out, cast=False), new_server
 
         def _per_leaf_server_phase(server, grads, seed):

@@ -91,16 +91,18 @@ def test_scope_table_gives_each_scope_its_work():
     none = sorted(k for k, v in table.items() if v is None and sets[k])
     # every scope reaches the compiled step
     assert set().union(*sets.values()) >= set(obs.SCOPES)
-    # XLA:CPU fuses the unpack slices and the stored-dtype casts of the
-    # async buffers into AdamW's fusions; the TPU compile of this step
-    # (tests/test_tpu_compile.py) gives those two scopes work of their own
+    # XLA:CPU fuses the unpacked leaves into AdamW's fusions and the
+    # stored-dtype casts into the server stages'; the TPU compile of this
+    # step (tests/test_tpu_compile.py) gives those two scopes work of
+    # their own
     fused_on_cpu = {"unpack", "server_cast"}
     assert owned >= set(obs.SCOPES) - fused_on_cpu, (
         f"scopes with no instruction of their own: "
         f"{set(obs.SCOPES) - owned}; top-level instructions with None: "
         f"{[(k, sorted(map(str, sets[k]))) for k in none]}")
     mixed = [sets[k] for k in none if len(sets[k]) > 1]
-    assert any(fused_on_cpu <= s for s in mixed)
+    assert any({"unpack", "adamw"} <= s for s in mixed)
+    assert any({"server_cast", "server_stages"} <= s for s in mixed)
 
 
 def test_leaf_scope_is_the_innermost():

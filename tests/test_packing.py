@@ -94,6 +94,40 @@ class TestLayout:
                 np.testing.assert_array_equal(
                     np.asarray(a, np.float32), np.asarray(b, np.float32))
 
+    @pytest.mark.parametrize("cast", [True, False], ids=["cast", "raw"])
+    @pytest.mark.parametrize("src", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_unpack_equals_slice_reshape_astype(self, src, cast):
+        """Bit for bit the plain per-leaf slice -> reshape -> astype, for
+        every leaf class of the launch path's buffer: minor widths 32, 256,
+        1024 and 2048, 1-D and scalar leaves, f32 and bf16 leaves, a leaf
+        that ends on a LANE boundary (3 x 256, no pad) and a buffer whose
+        last 1024-tile is partial (9,984 coordinates)."""
+        specs = [((5, 32), jnp.float32), ((3, 256), jnp.float32),
+                 ((2, 1024), jnp.bfloat16), ((3, 4, 32), jnp.float32),
+                 ((2, 3, 256), jnp.float32), ((1, 2, 2048), jnp.float32),
+                 ((100,), jnp.float32), ((7,), jnp.bfloat16),
+                 ((), jnp.float32)]
+        lay = packing.PackedLayout.from_tree(
+            [jax.ShapeDtypeStruct(s, dt) for s, dt in specs])
+        assert lay.table[1].pad == 0 and lay.d_packed % 1024
+        flat = jnp.asarray(np.random.default_rng(0).standard_normal(
+            lay.d_packed).astype("f4")).astype(src)
+
+        def plain(flat):
+            out = []
+            for e in lay.table:
+                leaf = jax.lax.slice(flat, (e.offset,),
+                                     (e.offset + e.size,)).reshape(e.shape)
+                out.append(leaf.astype(e.dtype) if cast else leaf)
+            return out
+
+        got = jax.jit(lambda f: lay.unpack(f, cast=cast))(flat)
+        for e, a, b in zip(lay.table, got, jax.jit(plain)(flat)):
+            assert (a.shape, a.dtype) == (b.shape, b.dtype) == (
+                e.shape, e.dtype if cast else flat.dtype)
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
     def test_pack_age_sentinel_and_init_age(self):
         tree = transformer_tree()
         _, _, age = tie_free_state(tree)

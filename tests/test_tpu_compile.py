@@ -13,7 +13,9 @@ worker imports this file."""
 from __future__ import annotations
 
 import os
+import re
 import sys
+from math import prod
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.core import packing
 from repro.kernels.fairk_update import (fairk_ef_update_pallas,
                                         fairk_stats_update_pallas)
 from repro.kernels.sign_mv import sign_from_energy_pallas, sign_mv_pallas
+from repro.optim import make_optimizer
 
 D = 64 * 65536          # 64 grid steps of the production block
 BLOCK = 65536
@@ -127,3 +130,70 @@ def test_scope_table_of_the_step_for_the_chip(one_chip, monkeypatch):
         f"scopes with no instruction of their own: "
         f"{set(obs.SCOPES) - set(table.values())}; top-level instructions "
         f"with None: {none}")
+
+
+# the leaf classes of mamba2-370m's packed server buffer: minor widths 32,
+# 256, 1024 and 2048 and a 1-D leaf, 48 rows a stack (at 8 rows XLA keeps
+# the per-leaf slices); the buffer's (d/128, 128) view has a row count that
+# is not a multiple of 8, as the cell's 2,878,028 rows
+UNPACK_LEAVES = [(48, 32), (48, 256), (48, 1024), (48, 1024, 32),
+                 (48, 1024, 256), (48, 1024, 2048), (1024,)]
+_INSTR = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+)\s+=\s+(.*)$")
+
+
+def _instructions(text):
+    """(name, opcode, [output dims]) of every instruction of an HLO module
+    (a tuple-shaped output gives each of its elements)."""
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        end = rest.find(" ")
+        if rest.startswith("("):
+            depth = 0
+            for end, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            end += 1
+        opcode = rest[end:].lstrip().split("(", 1)[0]
+        dims = [tuple(int(x) for x in d.split(",") if x)
+                for d in re.findall(r"\w+\[([\d,]*)\]", rest[:end])]
+        yield name, opcode, dims
+
+
+@pytest.mark.parametrize("stored", ["bf16", "kernel-f32"])
+def test_unpack_relays_each_leaf_not_the_buffer(one_chip, stored):
+    """The packed update phase (unpack, then AdamW) moves each leaf out of
+    its own slot: no op holds the whole buffer in a shape other than the
+    stored 1-D buffer or the kernel's (d/128, 128) view, and each leaf is
+    relaid once.  ``stored``: ``pending`` (bf16, 1-D) or the kernel's f32
+    output."""
+    tree = [jax.ShapeDtypeStruct(s, jnp.float32) for s in UNPACK_LEAVES]
+    layout = packing.PackedLayout.from_tree(tree)
+    d, rows = layout.d_packed, layout.d_packed // 128
+    assert rows % 8
+    opt = make_optimizer("adamw", 1e-3)
+
+    def update_phase(buf, params, opt_state):
+        g = layout.unpack(buf.reshape(d))
+        g = jax.tree.map(lambda gt, p: gt.astype(p.dtype), g, params)
+        updates, new_opt = opt.update(g, opt_state, params)
+        return jax.tree.map(lambda p, u: p + u, params, updates), new_opt
+
+    buf = (_sds(one_chip, (d,), jnp.bfloat16) if stored == "bf16"
+           else _sds(one_chip, (rows, 128)))
+    params = [_sds(one_chip, s) for s in UNPACK_LEAVES]
+    opt_state = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                             jax.eval_shape(opt.init, tree))
+    text = jax.jit(update_phase, donate_argnums=(1, 2)).lower(
+        buf, params, opt_state).compile().as_text()
+    ops = list(_instructions(text))
+    whole = [(name, op, dims) for name, op, out in ops for dims in out
+             if prod(dims) == d and dims not in ((d,), (rows, 128))]
+    assert not whole, f"whole-buffer relayouts: {whole}"
+    for shape in UNPACK_LEAVES[:-1]:
+        relaid = [name for name, op, out in ops
+                  if op == "reshape" and out == [shape]]
+        assert len(relaid) <= 1, f"{shape} relaid {len(relaid)} times"
