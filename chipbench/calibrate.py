@@ -10,11 +10,14 @@ against the same reference: the lower-precision control (the reference
 with every value the program holds in bfloat16 rounded to float8,
 ``check.fp8``), and planted faults in the reference put in the program's
 place: half the batch left out (the first half of each round's
-microbatches, the mean taken over them) and a server that refreshes a
-uniform draw of about k coordinates instead of its selection.  A step
+microbatches, the mean taken over them), a server that refreshes a
+uniform draw of about k coordinates instead of its selection, and, on
+several chips, the exchange of gradients between them left out (each
+shard's coordinates take the gradient of its own rows).  A step
 that returns its state unchanged reads 1 on the norm gaps by definition
 and needs no run.  No seed is begun once ``--budget-s`` seconds have
-passed.  One JSON line per reading."""
+passed.  One JSON line per reading; a fault that crashes is recorded
+with its error."""
 
 from __future__ import annotations
 
@@ -63,9 +66,11 @@ def main(argv=None) -> int:
             prog.compile(state, pool[0])
             state, got = harness.program_readings(prog, state, pool, seed)
         del state
-        gc.collect()
+        prog.release()
         t_ref = time.perf_counter()
-        want = check.reference_readings(cell, seed, pool, prog.abstract)
+        place = harness.reference_shardings(prog)
+        want = check.reference_readings(cell, seed, pool, prog.abstract,
+                                        shardings=place)
         emit({"seed": seed, "kind": "program", **check.gaps(got, want),
               "losses": got["losses"], "ref_losses": want["losses"],
               "n_sel": [r["n_sel"] for r in got["server"]],
@@ -73,12 +78,20 @@ def main(argv=None) -> int:
               "s": time.perf_counter() - t0,
               "reference_s": time.perf_counter() - t_ref})
         if i < opts.faults:
-            for kind, kw in (("control_fp8", {"quant": check.fp8}),
-                             ("half_batch", {"micro": prog.n_micro // 2}),
-                             ("random_selection", {"selection": "random"})):
+            faults = [("control_fp8", {"quant": check.fp8}),
+                      ("half_batch", {"micro": prog.n_micro // 2}),
+                      ("random_selection", {"selection": "random"})]
+            if cell.chips > 1:
+                faults.append(("no_exchange", {"exchange": False}))
+            for kind, kw in faults:
                 t0 = time.perf_counter()
-                bad = check.reference_readings(cell, seed, pool,
-                                               prog.abstract, **kw)
+                try:
+                    bad = check.reference_readings(cell, seed, pool,
+                                                   prog.abstract,
+                                                   shardings=place, **kw)
+                except Exception as err:  # a fault that crashes has failed
+                    emit({"seed": seed, "kind": kind, "error": repr(err)})
+                    continue
                 emit({"seed": seed, "kind": kind, **check.gaps(bad, want),
                       "s": time.perf_counter() - t0})
         del pool

@@ -80,27 +80,38 @@ def _loss_fn(reference: str, model_json: str, quant):
 
 
 def reference_readings(cell, seed: int, batches, abstract, *,
-                       quant=None, micro: Optional[int] = None,
+                       shardings=None, quant=None,
+                       micro: Optional[int] = None, exchange: bool = True,
                        selection: str = "exact") -> Dict[str, Any]:
-    """The reference's losses and norms over the checked rounds.  ``quant``
-    rounds what the program holds in its compute dtype (the control);
-    ``micro`` keeps only that many microbatches a round, ``selection``
-    another server selection (planted faults)."""
+    """The reference's losses and norms over the checked rounds.  The
+    weights are placed by ``shardings`` (the program's parameter
+    shardings), and so are the gradients, the optimizer's moments and the
+    server's trees that follow them: the plain code is spread over the
+    program's devices.  ``quant`` rounds what the program holds in its
+    compute dtype (the control); ``micro`` keeps only that many
+    microbatches a round, ``exchange=False`` leaves out the exchange of
+    gradients between devices, ``selection`` another server selection
+    (planted faults)."""
     import dataclasses
     import jax
     from chipbench import feed
     from chipbench.reference import fairk_adamw
     loss_fn = _loss_fn(cell.config["reference"],
                        json.dumps(cell.config["model"], sort_keys=True), quant)
-    params = jax.jit(feed.init_weights)(
+    params = jax.jit(feed.init_weights, out_shardings=shardings)(
         jax.random.PRNGKey(feed.seed32(seed, 0)), abstract)
     srv = dataclasses.replace(
         fairk_adamw.parse_server(cell.traffic["server_flags"]),
         selection=selection)
+    train = functools.partial(
+        fairk_adamw.train, batches=batches, loss_fn=loss_fn, srv=srv,
+        lr=cell.traffic["lr"], steps=len(batches), micro=micro,
+        exchange=exchange, seed=feed.seed32(seed, 2))
     with jax.default_matmul_precision("highest"):
-        out = fairk_adamw.train(params, batches, loss_fn, srv,
-                                lr=cell.traffic["lr"], steps=len(batches),
-                                micro=micro, seed=feed.seed32(seed, 2))
+        # sharded over several devices, the gradient program cannot load
+        # beside the moments and the server's trees once the program's step
+        # has run (PERF.md, Open questions): those wait on the host then
+        out = train(params, park=shardings is not None)
     out["delta"] = feed.delta_norms(out.pop("params"), seed, abstract)
     return out
 
@@ -164,9 +175,10 @@ def gaps(got: Dict[str, Any], want: Dict[str, Any]) -> Dict[str, float]:
             **server_gaps(got["server"], want["server"])}
 
 
-def compare(cell, seed: int, prog_read, batches, abstract
+def compare(cell, seed: int, prog_read, batches, abstract, shardings=None
             ) -> Dict[str, Dict[str, float]]:
-    want = reference_readings(cell, seed, batches, abstract)
+    want = reference_readings(cell, seed, batches, abstract,
+                              shardings=shardings)
     got = gaps(prog_read, want)
     return {k: {"value": got[k], "limit": cell.limits[k]} for k in NUMBERS
             if k in cell.limits}

@@ -54,13 +54,44 @@ def ssm_forward_flops_per_token(m: Dict[str, Any]) -> int:
     return m["n_layers"] * per_layer + 2 * m["d_model"] * m["vocab"]
 
 
-FORWARD = {"ssd_lm": ssm_forward_flops_per_token}
+def _mqa_layer_weights(m: Dict[str, Any]) -> int:
+    """Matmul weights of one layer: q, k, v, o and the two FFN matrices."""
+    d, hd = m["d_model"], m["d_model"] // m["n_heads"]
+    return (d * (m["n_heads"] + 2 * m["n_kv_heads"]) * hd
+            + m["n_heads"] * hd * d + 2 * d * m["d_ff"])
+
+
+def mqa_param_count(m: Dict[str, Any]) -> int:
+    """Parameters of a pre-LayerNorm MQA LM with a biased GELU FFN: per
+    layer the matmul weights, the FFN's two biases and two LayerNorms
+    (scale and bias); the embedding, the untied head and the final
+    LayerNorm."""
+    per_layer = _mqa_layer_weights(m) + m["d_ff"] + m["d_model"] \
+        + 4 * m["d_model"]
+    return (m["n_layers"] * per_layer + 2 * m["vocab"] * m["d_model"]
+            + 2 * m["d_model"])
+
+
+def mqa_forward_flops_per_token(m: Dict[str, Any], seq_len: int) -> int:
+    """Forward FLOPs per token: 2 per multiply-add of the layers' matmul
+    weights and the head, and causal attention's scores and weighted
+    values, 2 x 2 x (seq_len / 2) x heads x head width a layer (a token
+    reads half the sequence on average)."""
+    hd = m["d_model"] // m["n_heads"]
+    attn = 2 * 2 * (seq_len // 2) * m["n_heads"] * hd
+    return (m["n_layers"] * (2 * _mqa_layer_weights(m) + attn)
+            + 2 * m["d_model"] * m["vocab"])
+
+
+# reference model -> forward FLOPs per token of (model, sequence length)
+FORWARD = {"ssd_lm": lambda m, _seq: ssm_forward_flops_per_token(m),
+           "mqa_lm": mqa_forward_flops_per_token}
 
 
 def train_flops_per_round(config: Dict[str, Any],
                           traffic: Dict[str, Any]) -> float:
     """Forward + backward (2x forward) model FLOPs of one round."""
-    fwd = FORWARD[config["reference"]](config["model"])
+    fwd = FORWARD[config["reference"]](config["model"], traffic["seq_len"])
     return 3.0 * fwd * traffic["seq_len"] * traffic["batch"]
 
 

@@ -25,6 +25,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import gc
+import hashlib
 import importlib.util
 import json
 import math
@@ -144,6 +145,7 @@ class Program:
                               out_shardings=self.out_sh,
                               donate_argnums=(0, 1, 2))
         self.compiled = None
+        self.root = cell.root
 
     def init(self, seed: int):
         """(params, opt_state, server) for ``seed``, on the device."""
@@ -166,26 +168,64 @@ class Program:
         import jax.numpy as jnp
         if self.compiled is None:
             with self.mesh:
-                self.compiled = self.jitted.lower(
-                    *state, batch, jnp.asarray(0, jnp.int32)).compile()
+                lowered = self.jitted.lower(*state, batch,
+                                            jnp.asarray(0, jnp.int32))
+                self.compiled = lowered.compile()
+            keep_module_text(self.root, lowered, self.compiled)
         return self.compiled
+
+    def release(self):
+        """Drop the compiled step before the reference (``compile`` builds
+        it again)."""
+        self.compiled = None
+        gc.collect()
 
     def __call__(self, state, batch, seed):
         params, opt, server, loss = self.compiled(*state, batch, seed)
         return (params, opt, server), loss
 
 
+def module_text_path(root: Path, lowered) -> Path:
+    """Where the optimised module text of a lowered program is kept, under
+    the compilation cache's directory, by a hash of its lowered text."""
+    key = hashlib.sha256(lowered.as_text().encode()).hexdigest()[:40]
+    return root / ".jax_cache" / "module_text" / f"{key}.txt"
+
+
+def keep_module_text(root: Path, lowered, compiled) -> None:
+    """Keep the optimised module text of a program this process compiled.
+    An executable the persistent cache serves comes without it, and the
+    scope and collective readers of a traced run need it (``scopes``)."""
+    path = module_text_path(root, lowered)
+    text = compiled.as_text()
+    if "ENTRY" in text and not path.exists():
+        path.parent.mkdir(parents=True, exist_ok=True)
+        tmp = path.with_suffix(f".{os.getpid()}.tmp")
+        tmp.write_text(text)
+        tmp.replace(path)
+
+
 def server_reader(prog: Program):
     """A jitted reading (``server_state.reading``) of the program's stored
-    server state; the one-chip flat buffers are read by their layout."""
+    server state.  The flat buffers are read by their layout: one chip's
+    whole, or each device's local buffer by its leaves' shard shapes under
+    the parameter shardings (``server_state.sharded_reading``)."""
     import jax
     from chipbench import server_state as ss
     from repro.core.controller import controller_state_from_vec
-    if prog.mesh.size != 1:
-        raise NotImplementedError("server readings cut one-chip buffers")
     lag = prog.oac.straggler_lag if prog.oac.async_agg else 0
+    leaves = jax.tree.leaves(prog.abstract)
+    local = [sh.shard_shape(l.shape)
+             for l, sh in zip(leaves, jax.tree.leaves(prog.in_sh[0]))]
+    spec = prog.in_sh[2]["g"].spec
 
     def fn(server):
+        if prog.mesh.size != 1:
+            return ss.sharded_reading(
+                {b: server[b] for b in ("g", "age") + ss.BUFFERS
+                 if b in server}, lag, local, [l.shape for l in leaves],
+                prog.mesh, spec, controller_state_from_vec(server["ctrl"])
+                if "ctrl" in server else None)
         norms = {("merged" if b == "g" else b):
                  ss.flat_norms(server[b], prog.abstract)
                  for b in ("g",) + ss.BUFFERS if b in server}
@@ -193,6 +233,12 @@ def server_reader(prog: Program):
                 if "ctrl" in server else None)
         return ss.reading(server["g"], server["age"], norms, lag, ctrl)
     return jax.jit(fn)
+
+
+def reference_shardings(prog: Program):
+    """Where the reference places its weights: as the program's
+    parameters over several devices; on one chip, where JAX puts them."""
+    return prog.in_sh[0] if prog.mesh.size > 1 else None
 
 
 def program_readings(prog: Program, state, pool, seed: int):
@@ -345,10 +391,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         counter.on = False
     peak = peak_bytes(cell.chips)
     del state
-    prog.compiled = None
-    gc.collect()
+    prog.release()
+    t_check = time.perf_counter()
     compared = check.compare(cell, seed, prog_read, pool[:CHECKED_STEPS],
-                             prog.abstract)
+                             prog.abstract, reference_shardings(prog))
+    check_s = time.perf_counter() - t_check
     del pool
     tokens = tr["seq_len"] * tr["batch"]
     rounds = len(win["round_s"])
@@ -392,6 +439,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                         "over_2x_median": sum(t > 2 * med
                                               for t in win["round_s"]),
                         "slowest": win["slowest"]}
+    out["check_s"] = check_s
     out["compared"] = compared
     return out
 

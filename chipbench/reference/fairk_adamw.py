@@ -32,6 +32,7 @@ refreshes nothing after round 0.  Imports nothing of the program."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -242,19 +243,61 @@ def _programs(loss_fn: Callable, srv: Server, k: int, k_m: int, lr: float):
     return _PROGRAMS[key]
 
 
+def _local_grad(grad_fn: Callable, params, tokens, labels):
+    """The gradient with the exchange between devices left out (a planted
+    fault): each device keeps, of the gradient over the rows that its
+    place on the mesh's ``data`` axis holds alone (the batch split along
+    dimension 1, as the program's batch sharding splits it), its own
+    shard; a leaf every device holds whole then differs from device to
+    device.  The kept shards wait on the host, so that no second gradient
+    tree is held on the devices."""
+    mesh = getattr(jax.tree.leaves(params)[0].sharding, "mesh", None)
+    if mesh is None or "data" not in mesh.shape:
+        return grad_fn(params, tokens, labels)
+    n = mesh.shape["data"]
+    data_axis = list(mesh.axis_names).index("data")
+    group = {d: int(np.argwhere(mesh.devices == d)[0][data_axis])
+             for d in mesh.devices.flat}
+    rows = tokens.shape[1] // n
+    lv, kept, like = 0.0, {}, None
+    for i in range(n):
+        part = slice(i * rows, (i + 1) * rows)
+        lv_i, g_i = grad_fn(params, tokens[:, part], labels[:, part])
+        lv += float(lv_i) / n
+        leaves, treedef = jax.tree_util.tree_flatten(g_i)
+        like = like or (treedef, [(l.shape, l.sharding) for l in leaves])
+        for j, leaf in enumerate(leaves):
+            for sh in leaf.addressable_shards:
+                if group[sh.device] == i:
+                    kept[j, sh.device] = np.asarray(sh.data)
+        del g_i, leaves
+    treedef, meta = like
+    out = [jax.make_array_from_single_device_arrays(
+        shape, sharding, [jax.device_put(kept[j, d], d)
+                          for d in sharding.addressable_devices])
+        for j, (shape, sharding) in enumerate(meta)]
+    return lv, jax.tree_util.tree_unflatten(treedef, out)
+
+
 def train(params, batches, loss_fn: Callable, srv: Server, *, lr: float,
-          steps: int = 3, micro: Optional[int] = None, seed: int = 0):
+          steps: int = 3, micro: Optional[int] = None,
+          exchange: bool = True, park: bool = False, seed: int = 0):
     """``steps`` rounds from ``params`` on ``batches`` (each a dict of
     ``(n_micro, mb, L)`` tokens and labels).  ``micro`` keeps only the
-    first ``micro`` microbatches of each round (a planted fault).  Returns
-    the losses, the per-leaf norms of the first non-zero gradient AdamW
-    received, the server's ``server_state.reading`` after each round, and
-    the parameters after the rounds."""
+    first ``micro`` microbatches of each round, ``exchange=False`` leaves
+    out the exchange of gradients between the devices the parameters are
+    sharded over (``_local_grad``): planted faults.  Returns the losses,
+    the per-leaf norms of the first non-zero gradient AdamW received, the
+    server's ``server_state.reading`` after each round, and the parameters
+    after the rounds.  ``park`` keeps the optimizer's moments and the
+    server's trees in host memory while the gradient is taken, so that the
+    devices hold the weights and the gradient program alone then."""
     d = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
     k = int(round(srv.rho * d))
     k_m = int(round(srv.k_m_frac * k))
     grad_fn, rounds, adamw, read = _programs(loss_fn, srv, k, k_m, lr)
-    zeros = lambda dt: jax.tree.map(lambda p: jnp.zeros(p.shape, dt), params)
+    # zeros placed as the parameters are (sharded where they are)
+    zeros = lambda dt: jax.tree.map(lambda p: jnp.zeros_like(p, dt), params)
     st = {"g_prev": zeros(jnp.bfloat16), "age": zeros(jnp.int8)}
     if srv.error_feedback:
         st["res"] = zeros(jnp.float32)
@@ -272,10 +315,20 @@ def train(params, batches, loss_fn: Callable, srv: Server, *, lr: float,
     key = jax.random.PRNGKey(seed)
     for t in range(steps):
         used = micro or batches[t]["tokens"].shape[0]
-        lv, g = grad_fn(params, batches[t]["tokens"][:used],
-                        batches[t]["labels"][:used])
+        if park:
+            # the moments and the server's trees wait on the host while
+            # the gradient is taken, the moments while the server runs
+            placed = jax.tree.map(lambda x: x.sharding, (m, v, st))
+            m, v, st = jax.device_get((m, v, st))
+        lv, g = (grad_fn if exchange else functools.partial(
+            _local_grad, grad_fn))(params, batches[t]["tokens"][:used],
+                                   batches[t]["labels"][:used])
         losses.append(float(lv))
+        if park:
+            st = jax.device_put(st, placed[2])
         out, st = rounds[t == 0](g, st, jax.random.fold_in(key, t))
+        if park:
+            m, v = jax.device_put((m, v), placed[:2])
         if ctrl is not None:
             ctrl = _controller(ctrl, st["age"])
         server.append(server_state.to_host(read(st, ctrl), keys))

@@ -3,11 +3,11 @@ program's own named scopes (``repro.obs``).
 
 The TPU trace names each op by its HLO instruction and carries no name
 stack; the scopes live in the compiled module's ``op_name`` metadata.  So
-``table(cell)`` compiles the cell's step once per process, from the same
+``table(cell)`` lowers the cell's step once per process, from the same
 ``harness.Program(cell)`` the window ran and at the window's argument
-shapes and shardings (the persistent compilation cache then serves the
-executable the window ran), and maps each top-level instruction to its
-scope with ``repro.obs.scope_table``.  A program without ``repro.obs``
+shapes and shardings, takes the optimised text of that program (kept
+when a run compiled it, or compiled anew), and maps each top-level
+instruction to its scope with ``repro.obs.scope_table``.  A program without ``repro.obs``
 has no scopes: ``table`` returns None and every scope reader reads
 nothing.
 
@@ -18,6 +18,7 @@ table does not know)."""
 
 from __future__ import annotations
 
+from types import SimpleNamespace
 from typing import Dict, Optional
 
 from chipbench import trace
@@ -29,6 +30,7 @@ METRICS = {"client_scope_ms": "client", "pack_ms": "pack",
            "adamw_ms": "adamw"}
 
 _tables: Dict[str, Optional[Dict[str, Optional[str]]]] = {}
+_texts: Dict[str, str] = {}
 
 
 def instruction(ev: trace.Event) -> str:
@@ -36,8 +38,8 @@ def instruction(ev: trace.Event) -> str:
     return ev[0].split(" = ")[0].strip().lstrip("%")
 
 
-def _compile(cell):
-    """The cell's train step compiled at the window's argument shapes and
+def _lower(cell):
+    """The cell's train step lowered at the window's argument shapes and
     shardings."""
     import jax
     import jax.numpy as jnp
@@ -62,7 +64,31 @@ def _compile(cell):
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         specs, prog.in_sh)
     with prog.mesh:
-        return prog.jitted.lower(*args).compile()
+        return prog.jitted.lower(*args)
+
+
+def compiled_text(cell) -> str:
+    """The optimised module text of the cell's compiled step, once per
+    process: the text kept when a run compiled the same lowered program
+    (``harness.keep_module_text``), or else that of a compile here."""
+    key = f"{cell.root}:{cell.name}"
+    if key not in _texts:
+        import jax
+        from chipbench import harness
+        lowered = _lower(cell)
+        path = harness.module_text_path(cell.root, lowered)
+        if not path.exists():
+            # an executable loaded from the persistent cache may come
+            # without its module's text: compile with the cache off
+            was = jax.config.jax_enable_compilation_cache
+            jax.config.update("jax_enable_compilation_cache", False)
+            try:
+                harness.keep_module_text(cell.root, lowered,
+                                         lowered.compile())
+            finally:
+                jax.config.update("jax_enable_compilation_cache", was)
+        _texts[key] = path.read_text()
+    return _texts[key]
 
 
 def table(cell) -> Optional[Dict[str, Optional[str]]]:
@@ -75,18 +101,8 @@ def table(cell) -> Optional[Dict[str, Optional[str]]]:
     except ImportError:
         _tables[cell.name] = None
         return None
-    import jax
-    compiled = _compile(cell)
-    if "ENTRY" not in compiled.as_text():
-        # an executable loaded from the persistent cache may come without
-        # its module's text: compile once more with the cache off
-        was = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        try:
-            compiled = _compile(cell)
-        finally:
-            jax.config.update("jax_enable_compilation_cache", was)
-    _tables[cell.name] = obs.scope_table(compiled)
+    text = compiled_text(cell)
+    _tables[cell.name] = obs.scope_table(SimpleNamespace(as_text=lambda: text))
     return _tables[cell.name]
 
 

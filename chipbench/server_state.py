@@ -1,10 +1,13 @@
 """What the FAIR-k server holds after a round, read alike on both sides.
 
-The program persists its server state as lane-aligned flat buffers (one
-chip: the parameter leaves in flatten order, each padded to a multiple of
-``LANE`` coordinates); the reference keeps trees.  ``flat_norms`` takes
-per-leaf norms of a flat buffer by that layout, and ``reading`` takes the
-same numbers from either:
+The program persists its server state as lane-aligned flat buffers: on
+each device, the parameter leaves' local shards in flatten order, each
+padded to a multiple of ``LANE`` coordinates, and over several devices
+those local buffers one after another.  The reference keeps trees.
+``flat_norms`` takes per-leaf norms of a one-chip flat buffer by that
+layout, ``sharded_reading`` reads a sharded server device by device with
+the same rule applied to each leaf's shard shape, and ``reading`` takes
+the same numbers from trees or one-chip buffers:
 
 - ``n_sel``: coordinates refreshed this round (their age is the delivery
   lag: 0, or the straggler lag under async; every other age is larger);
@@ -52,6 +55,66 @@ def flat_norms(flat, abstract):
     return out
 
 
+def sharded_reading(buffers: Dict[str, Any], lag: int, local_shapes,
+                    global_shapes, mesh, spec, ctrl=None) -> Dict[str, Any]:
+    """``reading`` of a server whose flat buffers (``g``, ``age`` and any of
+    ``BUFFERS``, each laid out as ``spec`` over ``mesh``) hold one local
+    buffer a device.  Each device sums its own leaves' lanes, by the
+    one-chip rule applied to ``local_shapes``; a leaf held whole or in part
+    on several devices (a replicated norm or bias) counts once, as in the
+    reference: its sums are divided by the number of devices that hold
+    each coordinate.  Counts are summed in int32.  Traceable."""
+    from jax.sharding import PartitionSpec as P
+    names = ["g", "age"] + [b for b in BUFFERS if b in buffers]
+    sizes = [int(np.prod(s)) for s in local_shapes]
+    offsets = np.cumsum([0] + [-(-n // LANE) * LANE for n in sizes])
+    n_dev = mesh.size
+    rep = np.array([n_dev * n / int(np.prod(s))
+                    for n, s in zip(sizes, global_shapes)], np.float32)
+
+    def local(*flats):
+        bufs = dict(zip(names, flats))
+        if bufs["g"].shape[0] != offsets[-1]:
+            raise ValueError(f"a local buffer of {bufs['g'].shape[0]} "
+                             f"coordinates is not the layout of {offsets[-1]}")
+        out = {"n_sel": [], "energy": [], "age_counts": [],
+               **{b: [] for b in names if b != "age"}}
+        for at, n in zip(offsets, sizes):
+            cut = {b: jax.lax.slice(x, (int(at),), (int(at) + n,))
+                   for b, x in bufs.items()}
+            a = cut["age"]
+            sel = a == lag
+            g2 = jnp.square(cut["g"].astype(jnp.float32))
+            out["n_sel"].append(jnp.sum(sel, dtype=jnp.int32))
+            out["energy"].append(jnp.sum(jnp.where(sel, g2, 0.0)))
+            out["age_counts"].append(jnp.stack(
+                [jnp.sum(a == v, dtype=jnp.int32)
+                 for v in range(AGE_READ - 1)]
+                + [jnp.sum(a >= AGE_READ - 1, dtype=jnp.int32)]))
+            for b in names:
+                if b != "age":
+                    out[b].append(jnp.sum(jnp.square(
+                        cut[b].astype(jnp.float32))))
+        return {k: jnp.stack(v)[None] for k, v in out.items()}
+
+    per_dev = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(names),
+                            out_specs=P(spec[0]))(
+        *(buffers[b] for b in names))
+    w = jnp.asarray(1.0 / rep)
+    total = {k: jnp.sum(v.astype(jnp.float32), 0) for k, v in per_dev.items()}
+    out = {"n_sel": jnp.sum(total["n_sel"] * w),
+           "energy": jnp.sum(total["energy"] * w),
+           "age_counts": jnp.sum(total["age_counts"] * w[:, None], 0)}
+    for b in names:
+        if b != "age":
+            norms = jnp.sqrt(total[b] * w)
+            out["merged" if b == "g" else b] = [norms[i]
+                                                for i in range(len(sizes))]
+    if ctrl is not None:
+        out["ctrl"] = ctrl_reading(ctrl)
+    return out
+
+
 def fold_ages(hist):
     """A histogram over unit age bins -> the ``age_counts`` bins."""
     hist = jnp.asarray(hist, jnp.float32)
@@ -89,11 +152,15 @@ def reading(g, age, leaf_norms: Dict[str, Any], lag: int, ctrl=None
         **leaf_norms,
     }
     if ctrl is not None:
-        ema = fold_ages(ctrl["age_ema"])
-        out["ctrl"] = {**{k: jnp.asarray(ctrl[k], jnp.float32)
-                          for k in CTRL_SCALARS},
-                       "age_pmf": ema / jnp.maximum(jnp.sum(ema), 1.0)}
+        out["ctrl"] = ctrl_reading(ctrl)
     return out
+
+
+def ctrl_reading(ctrl) -> Dict[str, Any]:
+    """The controller's scalars and its age EMA as a distribution."""
+    ema = fold_ages(ctrl["age_ema"])
+    return {**{k: jnp.asarray(ctrl[k], jnp.float32) for k in CTRL_SCALARS},
+            "age_pmf": ema / jnp.maximum(jnp.sum(ema), 1.0)}
 
 
 def to_host(r: Dict[str, Any], keys: List[str]) -> Dict[str, Any]:

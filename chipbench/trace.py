@@ -6,8 +6,9 @@ text, cut to ``NAME_CHARS``, its start and duration in ns) and the
 harness's host spans (``feed``, ``dispatch``, ``block``).  The TPU trace
 carries no name stack, but its op events nest: a ``while`` op's interval
 holds the ops of its body.  ``Context`` works on the top-level ops: the
-busy union and idle share, summed time by a predicate, and the breakdown.
-The metric readers under ``metrics/`` call it."""
+busy union and idle share, summed time by a predicate, and the breakdown;
+``Context.exposed_per_round_ms`` alone looks inside the loops, at every op
+event.  The metric readers under ``metrics/`` call it."""
 
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ import dataclasses
 import glob
 import json
 import os
+import re
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 HOST_SPANS = ("feed", "dispatch", "block")
@@ -64,6 +66,19 @@ def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return [(s, e) for s, e in out]
 
 
+def overlap(a: List[Tuple[float, float]], b: List[Tuple[float, float]]
+            ) -> float:
+    """Length of the overlap of two lists of sorted disjoint intervals."""
+    out, i, j = 0, 0, 0
+    while i < len(a) and j < len(b):
+        out += max(0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
 def top_level(events: List[Event]) -> List[Event]:
     """The events no other event's interval holds."""
     out, end = [], float("-inf")
@@ -101,6 +116,90 @@ def in_update_phase(ev: Event) -> bool:
     """Every other top-level op of the step: the server phase and AdamW
     under the ``shard_map`` update, and the gradient's scaling."""
     return not in_client_phase(ev)
+
+
+# HLO opcodes that move data between devices; each also has an async
+# ``-start`` / ``-done`` pair
+COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce", "all-to-all",
+               "collective-permute")
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=\s*(.*)$")
+_OPCODE = re.compile(r"\s([a-z][\w-]*)\(")
+_CALLS = re.compile(r"calls=%?([\w.-]+)")
+_COMP = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.-]+)\s+\(.*\{\s*$")
+
+
+def _opcode(rhs: str) -> str:
+    """The opcode of an instruction's right-hand side: the first word
+    called after its result shape."""
+    m = _OPCODE.search(rhs.split(", metadata=")[0])
+    return m.group(1) if m else ""
+
+
+def _is_collective_opcode(op: str) -> bool:
+    return op in COLLECTIVES or any(op in (c + "-start", c + "-done")
+                                    for c in COLLECTIVES)
+
+
+# opcodes whose interval holds the ops of another computation, and those
+# that compute on the matrix unit
+LOOPS = ("while", "conditional", "call")
+MATMULS = ("dot", "convolution")
+
+
+def instruction_kinds(hlo_text: str) -> Dict[str, str]:
+    """The kind of each instruction of a compiled module (``as_text()``):
+    ``collective`` where it only moves data between devices (a collective
+    opcode or its async start or done, or a fusion or async wrapper whose
+    called computations hold one and no dot or convolution, at any depth),
+    ``collective_matmul`` for a fusion that holds both, ``loop`` for a
+    ``while``, ``conditional`` or ``call``, and ``work`` for the rest."""
+    comps: Dict[str, List[Tuple[str, str, List[str]]]] = {}
+    cur = None
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m and cur is not None:
+            rhs = m.group(2)
+            comps[cur].append((m.group(1), _opcode(rhs),
+                               _CALLS.findall(rhs)))
+            continue
+        c = _COMP.match(line)
+        if c:
+            cur = c.group(1)
+            comps[cur] = []
+    held: Dict[str, set] = {}
+
+    def holds(name: str, seen=()) -> set:
+        """What a computation holds, through its fusions: 'collective',
+        'matmul'."""
+        if name not in held:
+            out = set()
+            for _, op, calls in comps.get(name, ()):
+                if _is_collective_opcode(op):
+                    out.add("collective")
+                elif op in MATMULS:
+                    out.add("matmul")
+                elif op in ("fusion", "async-start"):
+                    for c in calls:
+                        if c not in seen:
+                            out |= holds(c, seen + (name,))
+            held[name] = out
+        return held[name]
+
+    kinds = {}
+    for body in comps.values():
+        for name, op, calls in body:
+            inner = (set().union(*(holds(c) for c in calls))
+                     if op in ("fusion", "async-start", "async-done")
+                     else set())
+            if _is_collective_opcode(op) or inner == {"collective"}:
+                kinds[name] = "collective"
+            elif inner == {"collective", "matmul"}:
+                kinds[name] = "collective_matmul"
+            elif op in LOOPS:
+                kinds[name] = "loop"
+            else:
+                kinds[name] = "work"
+    return kinds
 
 
 @dataclasses.dataclass
@@ -159,6 +258,30 @@ class Context:
         if not evs:
             return None
         return sum(e[2] for e in evs) / 1e6 / self.rounds / len(devs)
+
+    def exposed_per_round_ms(self, pred: Callable[[Event], bool],
+                             work: Callable[[Event], bool]
+                             ) -> Optional[float]:
+        """Device time per round and per device, in ms, in which an op
+        event ``pred`` picks runs, at any depth (inside loops too), and no
+        event ``work`` picks does: the union of the first's intervals in
+        the window less its overlap with the union of the second's.  None
+        where ``pred`` picks no op."""
+        lo, hi = self.bounds()
+        devs = list(self.trace["devices"])
+        total, found = 0, False
+        for d in devs:
+            evs = [(max(e[1], lo), min(e[1] + e[2], hi), e)
+                   for e in self.trace["devices"][d]
+                   if e[1] + e[2] > lo and e[1] < hi]
+            mine = union([(s, e) for s, e, ev in evs if pred(ev)])
+            busy = union([(s, e) for s, e, ev in evs
+                          if work(ev) and not pred(ev)])
+            found = found or bool(mine)
+            total += sum(e - s for s, e in mine) - overlap(mine, busy)
+        if not found:
+            return None
+        return total / 1e6 / self.rounds / len(devs)
 
     # -- breakdown ------------------------------------------------------------
     def breakdown(self, top: int = 10) -> Dict[str, list]:
