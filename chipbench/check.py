@@ -39,13 +39,12 @@ and batches the benchmark made (``feed``)."""
 from __future__ import annotations
 
 import functools
-import importlib
 import json
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import server_state
+from chipbench import flops, server_state
 
 NUMBERS = ("loss_gap", "grad_norm_gap", "update_norm_gap", "sel_count_gap",
            "refresh_energy_gap", "merged_norm_gap", "age_hist_gap",
@@ -73,7 +72,7 @@ def fp8(x):
 def _loss_fn(reference: str, model_json: str, quant):
     """One loss function object for each model and rounding, so that the
     reference's jitted programs are built once a process."""
-    model = importlib.import_module(f"chipbench.reference.{reference}")
+    model = flops.reference(reference)
     m = json.loads(model_json)
     kw = {} if quant is None else {"quant": quant}
     return lambda p, t, l: model.loss(p, t, l, m, **kw)

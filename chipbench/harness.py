@@ -2,9 +2,11 @@
 
 A cell is found by name in ``BENCHMARK.json``: its configuration file
 (``configs/``), its traffic file (``traffic/<traffic>.json``), its limits
-(``limits/<cell>.json``) and, in a traced run, one reader per per-layer
-metric (``metrics/<metric>.py``).  Adding a cell, a configuration, a
-traffic mix or a metric adds files and entries; nothing here changes.
+(``limits/<cell>.json``), its plain reference
+(``reference/<reference>.py``, with ``loss`` and the two counts of
+``flops``) and, in a traced run, one reader per per-layer metric
+(``metrics/<metric>.py``).  Adding a cell, a configuration, a traffic mix
+or a metric adds files and entries; nothing here changes.
 
 The run drives the program's own compiled train step
 (``repro.launch.steps.make_train_step``, jitted as ``repro.launch.train.run``
@@ -38,11 +40,14 @@ import time
 from pathlib import Path
 from typing import Any, Dict, List
 
+from chipbench import flops
+
 ROOT = Path(__file__).resolve().parent.parent
 CHECKED_STEPS = 3
 POOL = 8
 TRACE_SECONDS = 2.0          # the traced part of a --trace 1 window
 TRACE_DIR = ".chipbench_trace"
+REFERENCE_API = ("loss", "param_count", "forward_flops_per_token")
 
 
 class NoChip(RuntimeError):
@@ -65,8 +70,24 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def check_reference(config: Dict[str, Any]) -> None:
+    """Refuse a configuration whose reference module lacks a function the
+    harness calls, or counts other parameters than its ``params``."""
+    name = f"chipbench.reference.{config['reference']}"
+    ref = flops.reference(config["reference"])
+    missing = [f for f in REFERENCE_API if not callable(getattr(ref, f, None))]
+    if missing:
+        raise ValueError(f"{name} has no {', '.join(missing)}")
+    count = ref.param_count(config["model"])
+    if count != config["params"]:
+        raise ValueError(f"{name}.param_count gives {count} parameters, "
+                         f"the configuration's params {config['params']}")
+
+
 def load_cell(name: str, root: Path = ROOT) -> Cell:
-    """Everything one cell needs, found by name from ``BENCHMARK.json``."""
+    """Everything one cell needs, found by name from ``BENCHMARK.json``;
+    a configuration its reference refuses (``check_reference``) raises
+    before any device is touched."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
@@ -78,9 +99,10 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if _applies(m, name) and m["moves"] in names]
+    config = json.loads((root / conf["file"]).read_text())
+    check_reference(config)
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=json.loads((root / conf["file"]).read_text()),
+        name=name, chips=int(w["chips"]), config=config,
         traffic=json.loads((base / "traffic" / f"{w['traffic']}.json")
                            .read_text()),
         limits=json.loads((base / "limits" / f"{name}.json").read_text()),

@@ -29,7 +29,9 @@ attention probabilities before they weight the values, the residual
 stream between layers (the lower-precision control); the identity for the
 reference itself.  Each layer and each block of query rows is
 rematerialised in the backward pass, so that one block's scores and
-probabilities are live at a time.  Imports nothing of the program."""
+probabilities are live at a time.  Imports nothing of the program.
+``param_count`` and ``forward_flops_per_token`` count the model from its
+config alone (``chipbench/flops.py`` states the rules)."""
 
 from __future__ import annotations
 
@@ -117,3 +119,32 @@ def loss(params, tokens, labels, m, quant=lambda t: t):
     logits = _mm(x, params["head"]["w"], quant)
     logp = jax.nn.log_softmax(logits, -1)
     return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], -1))
+
+
+def _layer_weights(m) -> int:
+    """Matmul weights of one layer: q, k, v, o and the two FFN matrices."""
+    d, hd = m["d_model"], m["d_model"] // m["n_heads"]
+    return (d * (m["n_heads"] + 2 * m["n_kv_heads"]) * hd
+            + m["n_heads"] * hd * d + 2 * d * m["d_ff"])
+
+
+def param_count(m) -> int:
+    """Parameters of a pre-LayerNorm MQA LM with a biased GELU FFN: per
+    layer the matmul weights, the FFN's two biases and two LayerNorms
+    (scale and bias); the embedding, the untied head and the final
+    LayerNorm."""
+    per_layer = _layer_weights(m) + m["d_ff"] + m["d_model"] \
+        + 4 * m["d_model"]
+    return (m["n_layers"] * per_layer + 2 * m["vocab"] * m["d_model"]
+            + 2 * m["d_model"])
+
+
+def forward_flops_per_token(m, seq_len: int) -> int:
+    """Forward FLOPs per token: 2 per multiply-add of the layers' matmul
+    weights and the head, and causal attention's scores and weighted
+    values, 2 x 2 x (seq_len / 2) x heads x head width a layer (a token
+    reads half the sequence on average)."""
+    hd = m["d_model"] // m["n_heads"]
+    attn = 2 * 2 * (seq_len // 2) * m["n_heads"] * hd
+    return (m["n_layers"] * (2 * _layer_weights(m) + attn)
+            + 2 * m["d_model"] * m["vocab"])

@@ -13,7 +13,8 @@ Every matmul runs at ``Precision.HIGHEST``.  ``quant`` rounds what the
 program holds in its compute dtype: both operands of each matmul, the
 SSD's inputs and the residual stream between layers (the lower-precision
 control); the identity for the reference itself.  Imports nothing of the
-program."""
+program.  ``param_count`` and ``forward_flops_per_token`` count the model
+from its config alone (``chipbench/flops.py`` states the rules)."""
 
 from __future__ import annotations
 
@@ -112,3 +113,40 @@ def loss(params, tokens, labels, m, quant=lambda t: t):
                         precision=HI)
     logp = jax.nn.log_softmax(logits, -1)
     return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], -1))
+
+
+def _ssm_layer(m):
+    d, n, g = m["d_model"], m["ssm_state"], m["ssm_groups"]
+    d_in = m["ssm_expand"] * d
+    h = d_in // m["ssm_head_dim"]
+    conv_ch = d_in + 2 * g * n
+    return {"d_in": d_in, "heads": h, "conv_ch": conv_ch,
+            "in_proj": d * (2 * d_in + 2 * g * n + h),
+            "out_proj": d_in * d}
+
+
+def param_count(m) -> int:
+    """Parameters of a Mamba-2 LM: per layer the projections, the
+    convolution weights and biases, A, D, dt_bias, the gated norm and two
+    pre-norms (the program keeps a second one unused); the embedding,
+    tied to the head, and the final norm."""
+    lay = _ssm_layer(m)
+    per_layer = (lay["in_proj"] + lay["out_proj"]
+                 + (m["ssm_conv"] + 1) * lay["conv_ch"]
+                 + 3 * lay["heads"] + lay["d_in"] + 2 * m["d_model"])
+    head = 0 if m["tie_embeddings"] else m["vocab"] * m["d_model"]
+    return (m["n_layers"] * per_layer + m["vocab"] * m["d_model"] + head
+            + m["d_model"])
+
+
+def forward_flops_per_token(m, seq_len: int) -> int:
+    """Forward FLOPs per token: 2 per multiply-add of the projections and
+    the head, the depthwise convolution, and the SSD recurrence's state
+    update (B x^T, 2 P N per head) and read-out (C h, 2 P N per head).
+    None of it grows with ``seq_len``."""
+    lay = _ssm_layer(m)
+    p, n = m["ssm_head_dim"], m["ssm_state"]
+    per_layer = (2 * (lay["in_proj"] + lay["out_proj"])
+                 + 2 * m["ssm_conv"] * lay["conv_ch"]
+                 + 4 * lay["heads"] * p * n)
+    return m["n_layers"] * per_layer + 2 * m["d_model"] * m["vocab"]

@@ -3,20 +3,22 @@
   PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q chipbench/tests
 
 The trace reduction on a recorded trace, the FLOP and byte counts, finding
-a cell's files by name, the refusal of a CPU device, reading the
-program's flat server buffers by leaf, the lower-precision control and
-planted selection faults failing the comparison, and a whole run (without
-the look for a chip) failing when the timed step is broken underneath."""
+a cell's files and its reference by name, the refusal of a CPU device and
+of a reference that cannot count its configuration, reading the program's
+flat server buffers by leaf, the lower-precision control and planted
+selection faults failing the comparison, and a whole run (without the look
+for a chip) failing when the timed step is broken underneath.  The sound
+run of each configuration's small case is ``test_reference_parity.py``'s."""
 
 from __future__ import annotations
 
 import gzip
 import json
 import os
-import shutil
 import subprocess
 import sys
 import time
+import types
 from pathlib import Path
 
 import pytest
@@ -26,19 +28,13 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chipbench import check, flops, harness, trace  # noqa: E402
+from chipbench import check, flops, harness, tinycell, trace  # noqa: E402
+from chipbench.tests import faults  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 CELL = "mamba2-370m.default.s2048x8"
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
-# limits for the two-layer model below, set like a cell's from its own
-# readings on the CPU: sound runs read at most grad 0.007, update 0.013,
-# selected count 0.24, refreshed energy 0.014, age histogram 0.25; the
-# float8 control reads grad 0.055-0.065, an empty selection 1 on the count
-# and the energy, a random one 0.87 on the energy
-TINY_LIMITS = {"grad_norm_gap": 0.03, "update_norm_gap": 0.2,
-               "sel_count_gap": 0.5, "refresh_energy_gap": 0.4,
-               "age_hist_gap": 0.6}
+MAMBA = "mamba2-370m"
 
 
 # ---------------------------------------------------------------------------
@@ -131,10 +127,20 @@ def test_flops_against_xla_cost_analysis(kw):
 
 
 def test_flops_and_params_of_the_cell():
+    from chipbench.reference import ssd_lm
     conf = json.loads((ROOT / "chipbench/configs/mamba2-370m.json")
                       .read_text())
-    assert flops.ssm_param_count(conf["model"]) == conf["params"] == 368387584
-    assert flops.ssm_forward_flops_per_token(conf["model"]) == 786481152
+    assert ssd_lm.param_count(conf["model"]) == conf["params"] == 368387584
+    assert ssd_lm.forward_flops_per_token(conf["model"], 2048) == 786481152
+
+
+@pytest.mark.parametrize("cell,per_round", [
+    ("mamba2-370m.default.s2048x8", 38_657_121_583_104),
+    ("mamba2-370m.full.s512x4", 4_832_140_197_888),
+    ("granite-34b-fsdp4.default.s2048x8", 145_187_074_473_984)])
+def test_flops_per_round_of_each_cell(cell, per_round):
+    c = harness.load_cell(cell)
+    assert flops.train_flops_per_round(c.config, c.traffic) == per_round
 
 
 def test_fairk_bytes():
@@ -153,42 +159,14 @@ def test_peaks_table():
 # cells found by name
 # ---------------------------------------------------------------------------
 
-def _tiny_root(tmp: Path, flags=(), limits=None) -> Path:
-    """A checkout holding one throwaway cell: config, traffic, limits and
-    a metric of their own, found by name alone."""
-    (tmp / "chipbench").mkdir(parents=True)
-    for d in ("configs", "traffic", "limits", "metrics"):
-        (tmp / "chipbench" / d).mkdir()
-    for f in (ROOT / "chipbench/metrics").glob("*.py"):
-        shutil.copy(f, tmp / "chipbench/metrics")
-    (tmp / "chipbench/metrics/rounds_seen.py").write_text(
+def test_cell_found_by_name(tmp_path):
+    root = tinycell.make_root(tmp_path, MAMBA)
+    (root / "chipbench/metrics/rounds_seen.py").write_text(
         "def read(ctx):\n    return float(ctx.rounds)\n")
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    bench["configs"] = [dict(bench["configs"][0], name="tiny",
-                             file="chipbench/configs/tiny.json")]
-    bench["workloads"] = [dict(bench["workloads"][0], name="tiny.t",
-                               config="tiny", traffic="t")]
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        m.pop("workloads", None)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
     bench["per_layer"].append(dict(bench["per_layer"][0],
                                    name="rounds_seen", unit="rounds"))
-    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
-    conf = json.loads((ROOT / "chipbench/configs/mamba2-370m.json")
-                      .read_text())
-    conf["model"] = dict(conf["model"], **_small_model(
-        n_layers=2, d_model=64, vocab=256, ssm_head_dim=16))
-    conf["params"] = flops.ssm_param_count(conf["model"])
-    (tmp / "chipbench/configs/tiny.json").write_text(json.dumps(conf))
-    (tmp / "chipbench/traffic/t.json").write_text(json.dumps(
-        {"seq_len": 128, "batch": 4, "server_flags": list(flags),
-         "lr": 1e-3}))
-    limits = limits or TINY_LIMITS
-    (tmp / "chipbench/limits/tiny.t.json").write_text(json.dumps(limits))
-    return tmp
-
-
-def test_cell_found_by_name(tmp_path):
-    root = _tiny_root(tmp_path)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
     cell = harness.load_cell("tiny.t", root)
     assert cell.config["model"]["d_model"] == 64
     assert cell.traffic["seq_len"] == 128
@@ -197,6 +175,63 @@ def test_cell_found_by_name(tmp_path):
     assert reader(trace.Context(cell, {}, 7, 1, "cpu")) == 7.0
     with pytest.raises(KeyError):
         harness.load_cell("no.such.cell", root)
+
+
+def _fresh_reference(root, monkeypatch, drop=None, params_off=0):
+    """A reference module of a fresh name, ``chipbench.reference.fresh_lm``
+    (delegating to ``ssd_lm``), that the root's configuration names;
+    without ``drop``, and with ``params`` off by ``params_off``."""
+    from chipbench.reference import ssd_lm
+    mod = types.ModuleType("chipbench.reference.fresh_lm")
+    mod.calls = []
+
+    def loss(*args, **kw):
+        mod.calls.append(args)
+        return "fresh"
+    mod.loss = loss
+    mod.param_count = ssd_lm.param_count
+    mod.forward_flops_per_token = ssd_lm.forward_flops_per_token
+    if drop:
+        delattr(mod, drop)
+    monkeypatch.setitem(sys.modules, mod.__name__, mod)
+    path = root / "chipbench/configs/tiny.json"
+    conf = json.loads(path.read_text())
+    conf["reference"] = "fresh_lm"
+    conf["params"] += params_off
+    path.write_text(json.dumps(conf))
+    return mod
+
+
+def test_reference_found_by_name(tmp_path, monkeypatch):
+    """A configuration whose reference module is new is found by
+    ``load_cell``, ``train_flops_per_round`` and ``check._loss_fn`` by its
+    name alone."""
+    from chipbench.reference import ssd_lm
+    root = tinycell.make_root(tmp_path, MAMBA)
+    mod = _fresh_reference(root, monkeypatch)
+    cell = harness.load_cell("tiny.t", root)
+    m, seq = cell.config["model"], cell.traffic["seq_len"]
+    assert flops.train_flops_per_round(cell.config, cell.traffic) == (
+        3 * ssd_lm.forward_flops_per_token(m, seq) * seq
+        * cell.traffic["batch"])
+    fn = check._loss_fn("fresh_lm", json.dumps(m, sort_keys=True), None)
+    assert fn("p", "t", "l") == "fresh"
+    assert mod.calls == [("p", "t", "l", m)]
+
+
+@pytest.mark.parametrize("drop,params_off,message", [
+    ("forward_flops_per_token", 0,
+     "chipbench.reference.fresh_lm has no forward_flops_per_token"),
+    (None, 1, "chipbench.reference.fresh_lm.param_count gives "
+     r"\d+ parameters, the configuration's params \d+")])
+def test_reference_refused_by_load_cell(tmp_path, monkeypatch, drop,
+                                        params_off, message):
+    """A reference that lacks a count, or whose parameter count is not
+    the configuration's ``params``, is refused when the cell loads."""
+    root = tinycell.make_root(tmp_path, MAMBA)
+    _fresh_reference(root, monkeypatch, drop=drop, params_off=params_off)
+    with pytest.raises(ValueError, match=message):
+        harness.load_cell("tiny.t", root)
 
 
 def test_cpu_device_is_refused():
@@ -262,7 +297,7 @@ def _over(compared) -> list:
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    root = _tiny_root(tmp_path_factory.mktemp("tiny"))
+    root = tinycell.make_root(tmp_path_factory.mktemp("tiny"), MAMBA)
     cell = harness.load_cell("tiny.t", root)
     return cell, harness.Program(cell)
 
@@ -302,42 +337,6 @@ def test_selection_fault_fails_the_comparison(tiny, selection, number,
         assert g[number] == pytest.approx(reading)
 
 
-class Unchanged(harness.Program):
-    """A step that returns its state unchanged."""
-
-    def __call__(self, state, batch, seed):
-        import jax
-        import jax.numpy as jnp
-        _, loss = super().__call__(jax.tree.map(jnp.copy, state), batch,
-                                   seed)
-        return state, loss
-
-
-class HalfBatch(harness.Program):
-    """A step that leaves out half of the batch and takes the mean over
-    the rest: the program's own step, built for half the microbatches."""
-
-    def __init__(self, cell):
-        half = dict(cell.traffic, batch=cell.traffic["batch"] // 2)
-        super().__init__(harness.Cell(**dict(vars(cell), traffic=half)))
-        self.full = cell.traffic["batch"] // self.micro_batch
-
-    def pool(self, seed, vocab, seq_len):
-        from chipbench import feed
-        return feed.batch_pool(seed, harness.POOL, self.full,
-                               self.micro_batch, seq_len, vocab,
-                               self.in_sh[3])
-
-    def _half(self, batch):
-        return {k: v[:self.n_micro] for k, v in batch.items()}
-
-    def compile(self, state, batch):
-        return super().compile(state, self._half(batch))
-
-    def __call__(self, state, batch, seed):
-        return super().__call__(state, self._half(batch), seed)
-
-
 class NoMagnitudeStage(harness.Program):
     """A server whose magnitude stage selects nothing after the first
     round: only the oldest coordinates are refreshed."""
@@ -357,7 +356,8 @@ class NoMagnitudeStage(harness.Program):
             engine.SelectionEngine._packed_thresholds = real
 
 
-@pytest.mark.parametrize("broken", [Unchanged, HalfBatch, NoMagnitudeStage])
+@pytest.mark.parametrize("broken", [faults.Unchanged, faults.HalfBatch,
+                                    NoMagnitudeStage])
 def test_broken_step_is_not_correct(tiny, broken):
     """A whole run, with the look for a chip skipped and the timed step
     broken underneath, reports correct false."""
@@ -372,25 +372,17 @@ FULL_SERVER = ("--ef", "--sanitize", "--async-agg", "--adaptive-km")
 
 
 @pytest.mark.parametrize("broken,reads", [(harness.Program, 0.0),
-                                          (Unchanged, 3.0)])
+                                          (faults.Unchanged, 3.0)])
 def test_controller_state_is_held_exactly(tmp_path, broken, reads):
     """Under the full server the adaptive split's state after the checked
     rounds (split, damped step, seen flag, round counter) matches the
     reference's exactly; a step that returns its state unchanged leaves
     the round counter three rounds behind."""
-    root = _tiny_root(tmp_path, flags=FULL_SERVER,
-                      limits={"ctrl_state_gap": 0.0})
+    root = tinycell.make_root(tmp_path, MAMBA, flags=FULL_SERVER,
+                              limits={"ctrl_state_gap": 0.0})
     cell = harness.load_cell("tiny.t", root)
     out = harness.run_cell(cell, 2 ** 33 + 5, 0.5, False, CPU,
                            time.perf_counter(), program_cls=broken)
     assert out["compared"]["ctrl_state_gap"]["value"] == reads
     assert out["correct"] is (reads == 0.0)
 
-
-def test_sound_run_is_correct(tiny):
-    cell, _ = tiny
-    out = harness.run_cell(cell, 2 ** 33 + 5, 0.5, False, CPU,
-                           time.perf_counter())
-    assert out["correct"] is True, out["compared"]
-    assert out["compiles_in_window"] == 0
-    assert list(out)[-1] == "compared"

@@ -144,11 +144,11 @@ def test_rebuilt_table_is_the_executed_one(tmp_path, tables):
     """The table ``scopes.table`` builds by compiling the cell's step at
     the window's shapes equals the one of the executable the run drove
     (two-layer model on the CPU)."""
-    sys.path.insert(0, str(Path(__file__).resolve().parent))
-    from test_chipbench import _tiny_root
+    from chipbench import tinycell
     from repro import obs
-    root = _tiny_root(tmp_path, flags=("--ef", "--sanitize", "--async-agg",
-                                       "--adaptive-km"))
+    root = tinycell.make_root(tmp_path, "mamba2-370m",
+                              flags=("--ef", "--sanitize", "--async-agg",
+                                     "--adaptive-km"))
     cell = harness.load_cell("tiny.t", root)
     prog = harness.Program(cell)
     state = prog.init(5)

@@ -7,15 +7,12 @@ on a hand-made module and trace, and, in subprocesses with four host
 devices (``--xla_force_host_platform_device_count=4``; this process keeps
 its one device): the four-shard server reading against the one-chip
 reading of the same values gathered, and whole runs of a two-layer
-``granite-34b`` cell on a ``(4, 1)`` mesh, sound and broken."""
+``granite-34b`` cell on a ``(4, 1)`` mesh, broken (its sound run is
+``test_reference_parity.py``'s)."""
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
-import textwrap
 from pathlib import Path
 
 import pytest
@@ -25,11 +22,11 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chipbench import flops, harness, scopes, trace  # noqa: E402
+from chipbench import flops, harness, scopes, tinycell, trace  # noqa: E402
 
 GRANITE = "granite-34b-fsdp4.default.s2048x8"
-TINY = dict(n_layers=2, d_model=256, n_heads=2, n_kv_heads=1, d_ff=512,
-            vocab=512)
+CONFIG = "granite-34b-fsdp4"
+TINY = tinycell.case(CONFIG)["model"]
 
 
 # ---------------------------------------------------------------------------
@@ -46,35 +43,37 @@ def _granite_cfg(**kw):
 
 @pytest.mark.parametrize("kw", [{}, TINY])
 def test_mqa_counts_agree_with_the_program(kw):
-    """``mqa_param_count`` counts every leaf of the program's parameter
+    """``mqa_lm.param_count`` counts every leaf of the program's parameter
     tree; the forward FLOPs without attention are twice the matmul
     weights that ``ModelConfig.param_count`` counts (all but the
     embedding, which is a gather)."""
     import jax
     import numpy as np
+    from chipbench.reference import mqa_lm
     from repro.models import transformer as tr
     cfg = _granite_cfg(**kw)
     m = dict(harness.load_cell(GRANITE).config["model"], **kw)
     abstract = jax.eval_shape(lambda k: tr.init_lm(k, cfg),
                               jax.random.PRNGKey(0))
-    assert flops.mqa_param_count(m) == sum(
+    assert mqa_lm.param_count(m) == sum(
         int(np.prod(l.shape)) for l in jax.tree.leaves(abstract))
     seq = 2048
     hd = m["d_model"] // m["n_heads"]
     attn = m["n_layers"] * 2 * 2 * (seq // 2) * m["n_heads"] * hd
-    fwd = flops.FORWARD["mqa_lm"](m, seq)
+    fwd = mqa_lm.forward_flops_per_token(m, seq)
     assert (fwd - attn) // 2 == cfg.param_count() - m["vocab"] * m["d_model"]
 
 
 def test_flops_and_params_of_the_granite_cell():
+    from chipbench.reference import mqa_lm, ssd_lm
     conf = harness.load_cell(GRANITE).config
-    assert flops.mqa_param_count(conf["model"]) == conf["params"] \
+    assert mqa_lm.param_count(conf["model"]) == conf["params"] \
         == 1741338624
     # 3 layers of 2 x 379,060,224 weights + 25,165,824 attention, and the
     # head's 2 x 301,989,888
-    assert flops.mqa_forward_flops_per_token(conf["model"], 2048) \
+    assert mqa_lm.forward_flops_per_token(conf["model"], 2048) \
         == 2953838592
-    assert flops.FORWARD["ssd_lm"](
+    assert ssd_lm.forward_flops_per_token(
         harness.load_cell("mamba2-370m.default.s2048x8").config["model"],
         2048) == 786481152
 
@@ -206,23 +205,12 @@ def test_collective_reader_sums_nested_collectives(monkeypatch):
 # four host devices, in a subprocess
 # ---------------------------------------------------------------------------
 
-def _run4(code: str, timeout: int = 900) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               PYTHONPATH=os.pathsep.join([str(ROOT / "src"), str(ROOT)]))
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       env=env, capture_output=True, text=True,
-                       timeout=timeout, cwd=str(ROOT))
-    assert p.returncode == 0, p.stderr[-4000:]
-    return json.loads(p.stdout.strip().splitlines()[-1])
-
-
 def test_four_shard_reading_equals_the_gathered_one():
     """A server packed as the program packs it over four devices (each
     device's local shards, leaf by leaf) reads as the one-chip buffer of
     the same values gathered: counts exactly, sums and norms to rounding.
     The norms and biases every device holds count once."""
-    got = _run4("""
+    got = tinycell.in_subprocess("""
         import dataclasses, json
         from types import SimpleNamespace
         import jax, jax.numpy as jnp, numpy as np
@@ -273,7 +261,7 @@ def test_four_shard_reading_equals_the_gathered_one():
         print(json.dumps({"four": four, "one": one,
                           "d_local": lay.d_packed,
                           "d": int(server["g"].shape[0])}))
-    """ % (GRANITE, TINY))
+    """ % (GRANITE, TINY), 4, 900)
     four, one = got["four"], got["one"]
     assert got["d"] > 0 and got["d"] % 4 == 0
     assert four["n_sel"] == one["n_sel"] > 0
@@ -286,105 +274,12 @@ def test_four_shard_reading_equals_the_gathered_one():
 
 
 RUN = """
-    import json, shutil, sys, time
+    import json
     from pathlib import Path
-    import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from chipbench import check, flops, harness
+    from chipbench import check, harness, tinycell
     from chipbench.reference import mqa_lm
-    tmp = Path(%(tmp)r)
-    for d in ("configs", "traffic", "limits", "metrics"):
-        (tmp / "chipbench" / d).mkdir(parents=True)
-    bench = json.loads(Path("BENCHMARK.json").read_text())
-    w = dict([x for x in bench["workloads"] if x["chips"] == 4][0],
-             name="tinyg.t", config="tinyg", traffic="t")
-    bench["configs"] = [dict(bench["configs"][1], name="tinyg",
-                             file="chipbench/configs/tinyg.json")]
-    bench["workloads"] = [w]
-    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
-    conf = harness.load_cell("%(cell)s").config
-    conf["model"] = dict(conf["model"], **%(tiny)r)
-    conf["params"] = flops.mqa_param_count(conf["model"])
-    (tmp / "chipbench/configs/tinyg.json").write_text(json.dumps(conf))
-    (tmp / "chipbench/traffic/t.json").write_text(json.dumps(
-        {"seq_len": 128, "batch": 8, "server_flags": [], "lr": 1e-3}))
-    (tmp / "chipbench/limits/tinyg.t.json").write_text(json.dumps(%(limits)r))
-    cell = harness.load_cell("tinyg.t", tmp)
-
-
-    class Unchanged(harness.Program):
-        def __call__(self, state, batch, seed):
-            _, loss = super().__call__(jax.tree.map(jnp.copy, state), batch,
-                                       seed)
-            return state, loss
-
-
-    class HalfBatch(harness.Program):
-        def __init__(self, cell):
-            half = dict(cell.traffic, batch=cell.traffic["batch"] // 2)
-            super().__init__(harness.Cell(**dict(vars(cell), traffic=half)))
-            self.full = cell.traffic["batch"] // self.micro_batch
-
-        def pool(self, seed, vocab, seq_len):
-            from chipbench import feed
-            return feed.batch_pool(seed, harness.POOL, self.full,
-                                   self.micro_batch, seq_len, vocab,
-                                   self.in_sh[3])
-
-        def _half(self, batch):
-            return {k: v[:self.n_micro] for k, v in batch.items()}
-
-        def compile(self, state, batch):
-            return super().compile(state, self._half(batch))
-
-        def __call__(self, state, batch, seed):
-            return super().__call__(state, self._half(batch), seed)
-
-
-    class NoExchange(harness.Program):
-        # the model's loss taken on each device over its own rows, on its
-        # own whole copy of the weights; in the backward pass each device
-        # keeps, of the gradient its rows give, the shard it holds, and
-        # nothing is summed between devices
-        def compile(self, state, batch):
-            from repro.models import transformer as tr
-            plain, mesh = tr.loss_fn, self.mesh
-            n = mesh.shape["data"]
-            dims = [next((i for i, e in enumerate(sh.spec) if e is not None
-                          and "data" in (e if isinstance(e, tuple) else (e,))),
-                         None) for sh in jax.tree.leaves(self.in_sh[0])]
-
-            @jax.custom_vjp
-            def spread(p):
-                return jax.tree.map(
-                    lambda x: jnp.broadcast_to(x, (n,) + x.shape), p)
-
-            def own(c, d):
-                if d is None:
-                    return c[0]
-                k = c.shape[d + 1] // n
-                return jnp.concatenate([jax.lax.slice_in_dim(
-                    c[i], i * k, (i + 1) * k, axis=d) for i in range(n)], d)
-
-            def bwd(_, ct):
-                leaves, treedef = jax.tree_util.tree_flatten(ct)
-                return (jax.tree_util.tree_unflatten(
-                    treedef, [own(c, d) for c, d in zip(leaves, dims)]),)
-            spread.defvjp(lambda p: (spread(p), None), bwd)
-
-            def local(params, cfg, mbatch, residual_fn=None):
-                return jax.shard_map(
-                    lambda p, b: plain(jax.tree.map(lambda x: x[0], p),
-                                       cfg, b),
-                    mesh=mesh, in_specs=(P("data"), P("data")),
-                    out_specs=P(), check_vma=False)(spread(params), mbatch)
-            tr.loss_fn = local
-            try:
-                return super().compile(state, batch)
-            finally:
-                tr.loss_fn = plain
-
-
+    from chipbench.tests import faults
+    root = Path(%(root)r)
     if %(mutate)r == "no_rope":
         mqa_lm.rope = lambda x, theta: x
     elif %(mutate)r == "second_kv_head":
@@ -395,9 +290,10 @@ RUN = """
     if %(broken)r == "control":
         # the reference, rounded to float8 where the program holds bfloat16,
         # in the program's place
+        cell = harness.load_cell(tinycell.CELL, root)
         prog = harness.Program(cell)
-        seed = 2 ** 33 + 5
-        pool = prog.pool(seed, conf["model"]["vocab"], 128)[:3]
+        seed = tinycell.SEED
+        pool = prog.pool(seed, cell.config["model"]["vocab"], 128)[:3]
         kw = dict(shardings=harness.reference_shardings(prog))
         want = check.reference_readings(cell, seed, pool, prog.abstract, **kw)
         got = check.reference_readings(cell, seed, pool, prog.abstract,
@@ -405,43 +301,20 @@ RUN = """
         g = check.gaps(got, want)
         compared = {k: {"value": g[k], "limit": v}
                     for k, v in cell.limits.items()}
-        print(json.dumps({"compared": compared, "correct": all(
-            c["value"] <= c["limit"] for c in compared.values())}))
-        sys.exit(0)
-    broken = {"sound": harness.Program, "unchanged": Unchanged,
-              "half_batch": HalfBatch, "no_exchange": NoExchange}[%(broken)r]
-    try:
-        out = harness.run_cell(cell, 2 ** 33 + 5, 0.5, False,
-                               {"platform": "cpu", "kind": "cpu", "count": 4},
-                               time.perf_counter(), program_cls=broken)
-    except Exception as err:
-        out = {"correct": False, "error": repr(err), "compared": {}}
+        out = {"compared": compared, "correct": all(
+            c["value"] <= c["limit"] for c in compared.values())}
+    else:
+        out = tinycell.run(root, faults.BROKEN[%(broken)r])
     print(json.dumps({k: out.get(k) for k in
                       ("correct", "compared", "compiles_in_window", "error")}))
 """
 
-# limits for the two-layer model on four CPU devices, set like a cell's
-# from its own readings: sound runs read at most grad 0.0014, update
-# 0.017; the float8 control reads grad 0.0048-0.0078, half the batch
-# 0.36-0.45, the exchange left out 1.0
-TINY4_LIMITS = {"grad_norm_gap": 0.003, "update_norm_gap": 0.05}
-
 
 def _run_cell4(tmp_path, broken="sound", mutate=None):
-    return _run4(RUN % {"tmp": str(tmp_path), "cell": GRANITE, "tiny": TINY,
-                        "limits": TINY4_LIMITS, "broken": broken,
-                        "mutate": mutate})
-
-
-def test_four_device_run_agrees_with_the_reference(tmp_path):
-    """The program's step on a ``(4, 1)`` mesh against the plain MQA
-    reference and FAIR-k server, sharded alike, at a two-layer
-    ``granite-34b``: the loss, each leaf's first gradient and the three
-    rounds' change agree within the limits."""
-    out = _run_cell4(tmp_path)
-    assert out["correct"] is True, out
-    assert out["compiles_in_window"] == 0
-    assert set(out["compared"]) == set(TINY4_LIMITS)
+    root = tinycell.make_root(tmp_path, CONFIG)
+    return tinycell.in_subprocess(
+        RUN % {"root": str(root), "broken": broken, "mutate": mutate}, 4,
+        900)
 
 
 @pytest.mark.parametrize("broken", ["unchanged", "half_batch",
